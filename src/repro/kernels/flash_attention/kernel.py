@@ -68,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit,
                    static_argnames=("bq", "bkv", "causal", "interpret"))
 def flash_attention(q, k, v, *, bq: int = 128, bkv: int = 128,
-                    causal: bool = True, interpret: bool = True):
+                    causal: bool = True, interpret: bool = False):
     """q: (B, H, S, D); k, v: (B, Hkv, S, D) -> (B, H, S, D)."""
     b, h, s, d = q.shape
     hk = k.shape[1]

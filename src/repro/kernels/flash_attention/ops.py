@@ -1,17 +1,14 @@
-"""jit'd public wrapper: picks the Pallas kernel on TPU, interpret mode elsewhere."""
+"""jit'd public wrapper. ``interpret=True`` runs the kernel body in the Pallas
+interpreter (any backend); the default compiles it, which only a TPU can."""
 from __future__ import annotations
-
-import jax
 
 from .kernel import flash_attention as _kernel_call
 from .ref import attention_ref
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bkv: int = 128, interpret: bool | None = None):
-    """q: (B, H, S, D); k, v: (B, Hkv, S, D). interpret=None -> auto (True off-TPU)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                    bkv: int = 128, interpret: bool = False):
+    """q: (B, H, S, D); k, v: (B, Hkv, S, D)."""
     return _kernel_call(q, k, v, bq=bq, bkv=bkv, causal=causal,
                         interpret=interpret)
 

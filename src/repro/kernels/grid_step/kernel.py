@@ -56,7 +56,7 @@ def _kernel(lab_prev_ref, lab_cur_ref, lab_next_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("band", "interpret"))
-def grid_step(labels, cond, *, band: int = 8, interpret: bool = True):
+def grid_step(labels, cond, *, band: int = 8, interpret: bool = False):
     """labels, cond: (H, W) int32 -> (H, W) one masked max-diffusion step."""
     h, w = labels.shape
     band = min(band, h)

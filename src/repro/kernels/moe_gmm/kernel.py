@@ -43,7 +43,7 @@ def _kernel(sizes_ref, x_ref, w_ref, o_ref, acc_scr, *, bc: int, bd: int, nd: in
 
 @functools.partial(jax.jit, static_argnames=("bc", "bf", "bd", "interpret"))
 def moe_gmm(x, w, group_sizes, *, bc: int = 128, bf: int = 128, bd: int = 128,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: (E, C, D); w: (E, D, F); group_sizes: (E,) int32 live rows per expert."""
     e, c, d = x.shape
     f = w.shape[-1]

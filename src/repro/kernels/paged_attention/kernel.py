@@ -116,7 +116,7 @@ def _verify_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
 
 @functools.partial(jax.jit, static_argnames=("sq", "interpret"))
 def paged_attention_verify(q, k_pages, v_pages, table, pos, *, sq: int,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B, Hkv, Sq*G, D) — ``sq`` query rows per kv head, (query, group)
     row-major; k_pages, v_pages: (P, page, Hkv, D); table: (B, maxp) int32;
     pos: (B,) int32 first query row's cache position -> (B, Hkv, Sq*G, D).
@@ -159,7 +159,7 @@ def paged_attention_verify(q, k_pages, v_pages, table, pos, *, sq: int,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, table, lengths, *,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, Hkv, G, D); k_pages, v_pages: (P, page, Hkv, D);
     table: (B, maxp) int32; lengths: (B,) int32 -> (B, Hkv, G, D)."""
     b, hk, g, d = q.shape

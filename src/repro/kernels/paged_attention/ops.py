@@ -1,7 +1,6 @@
-"""jit'd public wrapper: picks the Pallas kernel on TPU, interpret mode elsewhere."""
+"""jit'd public wrapper. ``interpret=True`` runs the kernel body in the Pallas
+interpreter (any backend); the default compiles it, which only a TPU can."""
 from __future__ import annotations
-
-import jax
 
 from .kernel import paged_attention as _kernel_call
 from .kernel import paged_attention_verify as _verify_call
@@ -9,11 +8,9 @@ from .ref import paged_attention_ref, paged_attention_verify_ref
 
 
 def paged_attention(q, k_pages, v_pages, table, lengths, *,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, H, D); k_pages, v_pages: (P, page, Hkv, D); table: (B, maxp) i32;
-    lengths: (B,) i32. interpret=None -> auto (True off-TPU)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    lengths: (B,) i32."""
     b, h, d = q.shape
     hk = k_pages.shape[2]
     out = _kernel_call(q.reshape(b, hk, h // hk, d), k_pages, v_pages,
@@ -22,15 +19,12 @@ def paged_attention(q, k_pages, v_pages, table, lengths, *,
 
 
 def paged_attention_verify(q, k_pages, v_pages, table, pos, *,
-                           interpret: bool | None = None):
+                           interpret: bool = False):
     """Batched k-position verify step (self-speculative decoding).
 
     q: (B, Sq, H, D) — query row ``r`` sits at cache position ``pos + r`` and
     attends causally up to it; k_pages, v_pages: (P, page, Hkv, D); table:
-    (B, maxp) i32; pos: (B,) i32. Returns (B, Sq, H, D).
-    interpret=None -> auto (True off-TPU)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    (B, maxp) i32; pos: (B,) i32. Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape
     hk = k_pages.shape[2]
     g = h // hk

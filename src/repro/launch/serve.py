@@ -1,4 +1,4 @@
-"""Serving entry point: batched prefill + decode on the host's devices, optionally
+"""Serving entry point: batched prefill + decode on one device, optionally
 restoring trained parameters from a checkpoint directory.
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --smoke \
@@ -21,8 +21,10 @@ request a seeded sampling lane instead of greedy:
         [--logprobs] [--attn-backend pallas_interpret] [--prefill-streams 2]
 
 ``--replicas N`` (with ``--stream``) serves the trace through the
-multi-replica placement router instead of one engine: N identical replicas,
-one global queue, per-tick placement under ``--router immune|rr|jsq`` —
+multi-replica placement router instead of one engine: N identical replicas
+(each on its own device when the host has at least N, else all on the
+default device), one global queue, per-tick placement under
+``--router immune|rr|jsq`` —
 immune placement routes by prefix affinity, drains anergic replicas, and
 prices backlog at remembered per-class cost (see serve/router.py):
 
@@ -57,6 +59,7 @@ fresh fleet recovers and finishes the trace with bitwise-identical streams:
 from __future__ import annotations
 
 import argparse
+import os
 import time
 
 import jax
@@ -64,13 +67,16 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.dist import checkpoint as ckpt
-from repro.launch.mesh import make_host_mesh
-from repro.models import layers as layers_mod
 from repro.models import model as model_lib
 from repro.serve import decode as serve_mod
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
 
-def main():
+
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's arguments — also the source of ``chip_smoke.py``'s
+    engine defaults, so the two cannot drift."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(configs.ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -187,17 +193,65 @@ def main():
     ap.add_argument("--show-stream", type=int, default=4,
                     help="print per-token stream deltas for this many "
                          "request ids (0 silences the stream)")
-    args = ap.parse_args()
+    return ap
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads
+    it itself), else the fixed ``<repo>/.jax_cache``. The path is part of
+    what makes a later run hit, so it never carries a pid, time or temporary
+    name. Library modules and the test suite leave the cache off."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_model(cfg, seed: int = 0):
+    """Random weights drawn from ``seed``, plus the zero router bias an MoE
+    stack serves with. Returns ``(params, bias)``."""
+    params = model_lib.init_params(jax.random.PRNGKey(seed), cfg)
+    bias = (jnp.zeros((cfg.num_layers, cfg.num_experts))
+            if cfg.num_experts else None)
+    return params, bias
+
+
+def stream_engine_config(args, max_cache: int):
+    """The ``--stream`` engine's configuration from the CLI's arguments."""
+    from repro.serve.engine import EngineConfig
+    return EngineConfig(
+        num_slots=args.slots, max_cache=max_cache,
+        policy=args.policy, num_classes=3,
+        latency_budget=args.latency_budget,
+        page_size=args.page_size, num_pages=args.pages,
+        prefill_chunk=args.prefill_chunk,
+        prefix_sharing=args.prefix_sharing,
+        attn_backend=args.attn_backend,
+        prefill_streams=args.prefill_streams,
+        pin_pages=args.pin_pages,
+        admission_mode=args.admission,
+        spec_decode=args.spec_decode,
+        spec_draft_layers=args.spec_draft_layers)
+
+
+def replica_devices(n: int) -> list:
+    """The device each of ``n`` engine replicas lives on: its own chip when
+    the host has at least ``n`` devices, else ``None`` (the default device)
+    for every replica."""
+    devices = jax.devices()
+    return list(devices[:n]) if len(devices) >= n else [None] * n
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
-    mesh = make_host_mesh()
-    layers_mod.set_mesh_axes(mesh)
-
-    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
-    bias = (jnp.zeros((cfg.num_layers, cfg.num_experts))
-            if cfg.num_experts else None)
+    params, bias = init_model(cfg)
     if args.restore:
         from repro.configs.base import TrainConfig
         from repro.train import train_step as ts
@@ -216,20 +270,8 @@ def main():
         from repro.serve import traces
         lcm = math.lcm(args.page_size, args.prefill_chunk or 1)
         raw = args.prompt_len + args.steps + 48
-        ecfg = eng_mod.EngineConfig(
-            num_slots=args.slots,
-            max_cache=-(-raw // lcm) * lcm,     # round up to page/chunk grain
-            policy=args.policy, num_classes=3,
-            latency_budget=args.latency_budget,
-            page_size=args.page_size, num_pages=args.pages,
-            prefill_chunk=args.prefill_chunk,
-            prefix_sharing=args.prefix_sharing,
-            attn_backend=args.attn_backend,
-            prefill_streams=args.prefill_streams,
-            pin_pages=args.pin_pages,
-            admission_mode=args.admission,
-            spec_decode=args.spec_decode,
-            spec_draft_layers=args.spec_draft_layers)
+        # round up to the page/chunk grain
+        ecfg = stream_engine_config(args, max_cache=-(-raw // lcm) * lcm)
         sampling = dict(temperature=args.temperature, top_p=args.top_p,
                         top_k=args.top_k, sample_seed=args.sample_seed)
         if args.trace == "shared-prefix":
@@ -289,7 +331,6 @@ def main():
             from repro.serve import router as rt_mod
             poweroff_plan = bool(args.faults) and "poweroff" in args.faults
             if poweroff_plan and not args.journal:
-                import os
                 import tempfile
                 args.journal = os.path.join(
                     tempfile.mkdtemp(prefix="serve_wal_"), "journal.wal")
@@ -304,36 +345,36 @@ def main():
                         FaultPlan.parse(args.faults),
                         engine_factory=lambda: eng_mod.Engine(
                             params, cfg, ecfg, router_bias=bias))
-                fleet = [eng_mod.Engine(params, cfg, ecfg, router_bias=bias)
-                         for _ in range(args.replicas)]
+                fleet = [eng_mod.Engine(params, cfg, ecfg, router_bias=bias,
+                                        device=d)
+                         for d in replica_devices(args.replicas)]
                 return rt_mod.Router(fleet,
                                      rt_mod.RouterConfig(policy=args.router),
                                      injector=injector)
 
             if args.faults:
                 print(f"fault plan: {args.faults}")
-            with mesh:
-                t0 = time.perf_counter()
-                if poweroff_plan:
+            t0 = time.perf_counter()
+            if poweroff_plan:
+                from repro.serve import durability
+                router, stats = durability.run_durable(
+                    make_router, trace, args.journal,
+                    snapshot_dir=args.snapshot_dir,
+                    snapshot_every=args.snapshot_every,
+                    sync_every=args.sync_every,
+                    max_ticks=50 * args.requests)
+            else:
+                router = make_router()
+                if args.journal:
                     from repro.serve import durability
-                    router, stats = durability.run_durable(
-                        make_router, trace, args.journal,
+                    router.attach_durability(
+                        durability.RequestJournal(
+                            args.journal, sync_every=args.sync_every),
                         snapshot_dir=args.snapshot_dir,
-                        snapshot_every=args.snapshot_every,
-                        sync_every=args.sync_every,
-                        max_ticks=50 * args.requests)
-                else:
-                    router = make_router()
-                    if args.journal:
-                        from repro.serve import durability
-                        router.attach_durability(
-                            durability.RequestJournal(
-                                args.journal, sync_every=args.sync_every),
-                            snapshot_dir=args.snapshot_dir,
-                            snapshot_every=args.snapshot_every)
-                    stats = router.run(trace, max_ticks=50 * args.requests)
-                    if router.journal is not None:
-                        router.journal.close()
+                        snapshot_every=args.snapshot_every)
+                stats = router.run(trace, max_ticks=50 * args.requests)
+                if router.journal is not None:
+                    router.journal.close()
             dt = time.perf_counter() - t0
             print(f"[{args.router} x {args.replicas}] {stats['completed']} "
                   f"completed / {stats['shed']} shed / {stats['rejected']} "
@@ -377,21 +418,20 @@ def main():
                       f"{d['snapshots']} snapshots")
             return
         eng = eng_mod.Engine(params, cfg, ecfg, router_bias=bias)
-        with mesh:
-            t0 = time.perf_counter()
-            # the streaming front door: RequestOutput deltas per tick, the
-            # terminal one carrying the finish reason + latency accounting
-            for out in eng.stream(trace, max_ticks=50 * args.requests):
-                if out.rid >= args.show_stream or \
-                        (not out.new_tokens and not out.finished):
-                    continue
-                tail = f" [{out.finish_reason}, {out.latency_ticks} ticks, " \
-                       f"{out.wall_latency_s * 1e3:.0f} ms]" \
-                    if out.finished and out.latency_ticks is not None \
-                    else (f" [{out.finish_reason}]" if out.finished else "")
-                print(f"  tick {out.tick:4d} req {out.rid} "
-                      f"+= {out.new_tokens}{tail}")
-            stats = eng.stats()
+        t0 = time.perf_counter()
+        # the streaming front door: RequestOutput deltas per tick, the
+        # terminal one carrying the finish reason + latency accounting
+        for out in eng.stream(trace, max_ticks=50 * args.requests):
+            if out.rid >= args.show_stream or \
+                    (not out.new_tokens and not out.finished):
+                continue
+            tail = f" [{out.finish_reason}, {out.latency_ticks} ticks, " \
+                   f"{out.wall_latency_s * 1e3:.0f} ms]" \
+                if out.finished and out.latency_ticks is not None \
+                else (f" [{out.finish_reason}]" if out.finished else "")
+            print(f"  tick {out.tick:4d} req {out.rid} "
+                  f"+= {out.new_tokens}{tail}")
+        stats = eng.stats()
         dt = time.perf_counter() - t0
         print(f"[{args.policy}] {stats['completed']} completed / "
               f"{stats['shed']} shed / {stats['rejected']} rejected of "
@@ -448,12 +488,11 @@ def main():
         prompts["frames"] = jax.random.normal(
             key, (args.batch, args.prompt_len, cfg.frontend_dim))
 
-    with mesh:
-        t0 = time.perf_counter()
-        toks, _ = serve_mod.generate(
-            params, cfg, prompts, max_cache=args.prompt_len + args.steps + 8,
-            steps=args.steps, router_bias=bias)
-        toks.block_until_ready()
+    t0 = time.perf_counter()
+    toks, _ = serve_mod.generate(
+        params, cfg, prompts, max_cache=args.prompt_len + args.steps + 8,
+        steps=args.steps, router_bias=bias)
+    toks.block_until_ready()
     dt = time.perf_counter() - t0
     print(f"{args.batch} x {args.steps} tokens in {dt:.1f}s (incl. compile); "
           f"{args.batch * args.steps / dt:.1f} tok/s")

@@ -418,9 +418,9 @@ def attention_decode_paged(params, x: Array, cfg: ModelConfig, cache: dict,
     if backend != "xla":
         # deferred import: layers must stay importable without the kernel pkg
         from ..kernels.paged_attention import paged_attention as paged_kernel
-        interpret = True if backend == "pallas_interpret" else None
         out = paged_kernel(q[:, 0], ck, cv, table,
-                           (pos + 1).astype(jnp.int32), interpret=interpret)
+                           (pos + 1).astype(jnp.int32),
+                           interpret=backend == "pallas_interpret")
         out = out.reshape(b, 1, -1).astype(v.dtype)
     else:
         gk = _gather_pages(ck, table)                  # (B, maxp*page, Hk, D)
@@ -466,10 +466,9 @@ def attention_verify_paged(params, x: Array, cfg: ModelConfig, cache: dict,
     if backend != "xla":
         # deferred import: layers must stay importable without the kernel pkg
         from ..kernels.paged_attention import paged_attention_verify
-        interpret = True if backend == "pallas_interpret" else None
         out = paged_attention_verify(q, ck, cv, table,
                                      pos.astype(jnp.int32),
-                                     interpret=interpret)
+                                     interpret=backend == "pallas_interpret")
         out = out.reshape(b, sq, -1).astype(v.dtype)
     else:
         gk = _gather_pages(ck, table)                  # (B, maxp*page, Hk, D)

@@ -491,7 +491,11 @@ class Engine:
     """Continuous-batching decode over a paged slot pool with queue admission."""
 
     def __init__(self, params, cfg: ModelConfig, ecfg: EngineConfig,
-                 router_bias: Optional[Array] = None):
+                 router_bias: Optional[Array] = None,
+                 device: Optional[jax.Device] = None):
+        """``device`` pins the engine to one chip: its params, page pool and
+        slot state are placed there and every tick's uploads land there
+        (``None``: the default device). A router gives each replica its own."""
         if ecfg.max_cache % ecfg.page_size:
             raise ValueError(f"max_cache {ecfg.max_cache} must be a multiple "
                              f"of page_size {ecfg.page_size}")
@@ -508,6 +512,9 @@ class Engine:
             raise ValueError(
                 f"spec_draft_layers must be in (0, {cfg.num_layers}), got "
                 f"{ecfg.spec_draft_layers}")
+        self.device = device
+        if device is not None:
+            params, router_bias = jax.device_put((params, router_bias), device)
         self.params, self.cfg, self.ecfg = params, cfg, ecfg
         self.router_bias = router_bias
         # MoE: the decode tick runs every slot, occupied or not, and expert
@@ -554,12 +561,14 @@ class Engine:
                          and len(transformer.segments(cfg)) == 1
                          and not cfg.frontend_dim and not cfg.frontend_tokens
                          and cfg.family not in ("audio", "vlm"))
-        self.pool = model.init_slot_cache_paged(cfg, s, ecfg.max_cache,
-                                                num_pages, ecfg.page_size)
-        self.last = jnp.zeros((s, 1), jnp.int32)
-        self.active = jnp.zeros((s,), bool)
-        self.frames = (jnp.zeros((s, 1, cfg.frontend_dim), jnp.float32)
-                       if cfg.family == "audio" else None)
+        with jax.default_device(device):
+            self.pool = model.init_slot_cache_paged(cfg, s, ecfg.max_cache,
+                                                    num_pages, ecfg.page_size)
+            self.last = jnp.zeros((s, 1), jnp.int32)
+            self.active = jnp.zeros((s,), bool)
+            self.frames = (jnp.zeros((s, 1, cfg.frontend_dim), jnp.float32)
+                           if cfg.family == "audio" else None)
+            self._null_spec = null_spec(s)   # all-greedy lanes, built once
         self.slots: list[Optional[ServeRequest]] = [None] * s
         self.jobs: deque[_PrefillJob] = deque()
         self.pos_host = np.zeros(s, np.int64)      # per-slot next write index
@@ -583,7 +592,6 @@ class Engine:
         # request's counts at each fold index equal its first run's)
         self.tok_counts = np.zeros((s, cfg.vocab_size), np.int32)
         self._spec_cache = None            # device copy of the samp_* rows
-        self._null_spec = null_spec(s)     # all-greedy lanes, built once
         self.queue: deque[ServeRequest] = deque()
         self.tick = 0
         self.completed: list[ServeRequest] = []
@@ -1437,7 +1445,11 @@ class Engine:
     def step(self):
         """One engine tick: admit into free slots, land a prefill chunk, decode
         one token for every active slot, retire finished sequences, advance the
-        immune states."""
+        immune states. Runs on the engine's device."""
+        with jax.default_device(self.device):
+            self._tick()
+
+    def _tick(self):
         self._admit()
         self._prefill_tick()
         self.concurrency_hw = max(self.concurrency_hw,

@@ -4,11 +4,12 @@ Installs the deterministic ``hypothesis`` fallback (_hypothesis_fallback.py)
 when the real package is missing, so airgapped environments still collect and
 run the property-test modules.
 
-(JAX's persistent compilation cache was evaluated here to hide the VLSI agent
-model's 20-30 s XLA CPU compiles on warm runs, and rejected: with
-``donate_argnums`` in play, deserialized CPU executables produced NaNs and
-heap corruption under jax 0.4.37. Do not re-enable without a correctness soak;
-see ROADMAP "Open items".)
+JAX's persistent compilation cache stays off for the test suite: only the
+chip entry points (``chip_smoke.py``, ``repro.launch.serve``) turn it on, at
+``JAX_COMPILATION_CACHE_DIR`` or ``<repo>/.jax_cache``. (It was once tried
+here to hide slow XLA CPU compiles and dropped after deserialized CPU
+executables with donated buffers misbehaved under an older jax; the suite
+has not been soaked with it on jax 0.9.)
 """
 import importlib.util
 import os

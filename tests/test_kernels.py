@@ -146,3 +146,25 @@ def test_grid_step_reaches_fixpoint_like_components():
     assert bool(jnp.all(jnp.where(cond.at[8:14, 5].set(0) == 1, lab == 7, True)))
     assert bool(jnp.all(jnp.where(jnp.zeros_like(cond).at[8:14, 5].set(1) == 1,
                                   lab == 9, True)))
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "flash_attention",
+                                    "moe_gmm", "grid_step"])
+def test_kernels_compile_by_default_and_refuse_off_tpu(kernel):
+    """No wrapper picks the interpreter by itself: without ``interpret=True``
+    each one compiles its kernel, which off a TPU raises instead of silently
+    interpreting."""
+    key = jax.random.PRNGKey(0)
+    calls = {
+        "paged_attention": lambda: paged_attention(
+            *_paged_fixture(key, 2, 4, 2, 32, num_pages=9, page=8, maxp=4,
+                            seed=0)),
+        "flash_attention": lambda: flash_attention(
+            *(jax.random.normal(key, (1, 2, 128, 64)),) * 3),
+        "moe_gmm": lambda: moe_gmm(jnp.ones((2, 32, 32)), jnp.ones((2, 32, 32)),
+                                   bc=32, bf=32, bd=32),
+        "grid_step": lambda: grid_step(jnp.ones((16, 32), jnp.int32),
+                                       jnp.ones((16, 32), jnp.int32)),
+    }
+    with pytest.raises(ValueError, match="interpret mode"):
+        jax.block_until_ready(calls[kernel]())

@@ -322,6 +322,16 @@ class TestPallasBackend:
                     f"[{backend}] request {req.rid} diverged from the oracle"
         assert outs["pallas_interpret"] == outs["xla"]
 
+    def test_compiled_backend_refuses_off_tpu(self):
+        """attn_backend='pallas' compiles the kernel: off a TPU the first
+        decode tick raises; it never falls back to the interpreter."""
+        cfg = _smoke_cfg("smollm-360m")
+        ecfg = eng_mod.EngineConfig(num_slots=2, max_cache=32,
+                                    prefill_chunk=8, attn_backend="pallas")
+        eng = eng_mod.Engine(_params(cfg), cfg, ecfg)
+        with pytest.raises(ValueError, match="interpret mode"):
+            eng.run(_make_requests(cfg, 1), max_ticks=20)
+
 
 class TestEngineMechanics:
     @pytest.fixture(scope="class")
