@@ -1,0 +1,7 @@
+"""Device time per decode-tick program execution in the traced window."""
+from harness import program_seconds
+
+
+def read(run):
+    calls = program_seconds(run, "decode_tick")
+    return 1e3 * sum(calls) / len(calls) if calls else None
