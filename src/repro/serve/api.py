@@ -175,6 +175,8 @@ class ServeRequest:
     finish_tick: int = -1
     slot: int = -1
     submit_time: float = -1.0              # wall clock, perf_counter seconds
+    admit_time: float = -1.0               # wall clock of the first seating
+    #                                        (kept across preemption)
     finish_time: float = -1.0
     preemptions: int = 0                   # times evicted from a slot mid-flight
     retries: int = 0                       # times re-placed on a survivor after

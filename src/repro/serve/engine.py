@@ -143,6 +143,8 @@ from .api import (RequestOutput, SamplingParams, ServeRequest,  # noqa: F401
                   spec_for)
 from .decode import greedy, null_spec
 from .paging import OutOfPages, PageAllocator, pages_for
+from .spans import (ADMIT, DECODE, IMMUNE, PAGES, PREFILL, RETIRE, STEP,
+                    pull, span)
 
 Array = jax.Array
 
@@ -377,7 +379,8 @@ class ImmuneAdmission:
     Per tick: completions feed the cost memory and the anergy
     stimulus/co-stimulus counters; ``end_tick`` advances the regulator (with the
     tick's admissions as stimulus) and the anergy gate (with IL-2 flowing when
-    queue pressure is low)."""
+    queue pressure is low). The states live on the device; every call runs
+    inside an ``IMMUNE`` span, nested in its caller's."""
 
     def __init__(self, ecfg: EngineConfig):
         self.ecfg = ecfg
@@ -392,26 +395,35 @@ class ImmuneAdmission:
         self._ok = np.zeros(c, np.float32)
 
     def remembered_cost(self, rclass: int) -> float:
-        return float(self.memory.value[rclass])
+        with span(IMMUNE):
+            return float(pull(self.memory.value)[rclass])
+
+    def anergy_level(self, rclass: int) -> float:
+        with span(IMMUNE):
+            return float(pull(self.anergy.level)[rclass])
 
     def observe_completion(self, rclass: int, cost: float, latency: float,
                            budget: Optional[float] = None):
-        # per-class EMA: observing `value` for the untouched classes leaves them
-        # unchanged under ImmuneMemory's decay*v + (1-decay)*obs update
-        self.memory = self.memory.update(
-            self.memory.value.at[rclass].set(cost))
-        if budget is None:
-            budget = self.ecfg.latency_budget
-        if latency > budget:
-            self._blown[rclass] += 1.0
-        else:
-            self._ok[rclass] += 1.0
+        with span(IMMUNE):
+            # per-class EMA: observing `value` for the untouched classes
+            # leaves them unchanged under ImmuneMemory's decay*v +
+            # (1-decay)*obs update
+            self.memory = self.memory.update(
+                self.memory.value.at[rclass].set(cost))
+            if budget is None:
+                budget = self.ecfg.latency_budget
+            if latency > budget:
+                self._blown[rclass] += 1.0
+            else:
+                self._ok[rclass] += 1.0
 
     def admissible(self, rclass: int) -> bool:
-        return float(self.anergy.level[rclass]) <= self.ecfg.shed_level
+        return self.anergy_level(rclass) <= self.ecfg.shed_level
 
     def throttled(self) -> bool:
-        return float(self.reg_state.response) > self.ecfg.reg_threshold
+        with span(IMMUNE):
+            return float(pull(self.reg_state.response)) \
+                > self.ecfg.reg_threshold
 
     def degrade(self, classes, severity: float):
         """Fleet capacity loss as an immune stress signal (graceful
@@ -427,9 +439,10 @@ class ImmuneAdmission:
         for k in classes:
             if 0 <= k < c:
                 stim[k] = min(max(float(severity), 0.0), 1.0)
-        self.anergy = self.gate.step(
-            self.anergy, stimulus=jnp.asarray(stim),
-            costimulus=jnp.zeros(c, jnp.float32), il2=0.0)
+        with span(IMMUNE):
+            self.anergy = self.gate.step(
+                self.anergy, stimulus=jnp.asarray(stim),
+                costimulus=jnp.zeros(c, jnp.float32), il2=0.0)
 
     def end_tick(self, admitted: int, queue_len: int,
                  queued_demand: np.ndarray, predicted_cost: np.ndarray):
@@ -441,19 +454,22 @@ class ImmuneAdmission:
         to prove it would let the convoy form first. In-budget completions are
         the co-stimulation; IL-2 flows when queue pressure drops, reviving shed
         classes so they are served in quiet periods."""
-        stim = jnp.asarray(admitted / max(self.ecfg.num_slots, 1), jnp.float32)
-        self.reg_state = self.regulator.step(self.reg_state, stim)
-        il2 = 1.0 if queue_len < self.ecfg.low_pressure * self.ecfg.num_slots \
-            else 0.0
-        will_blow = (queued_demand > 0) & \
-            (predicted_cost > self.ecfg.latency_budget)
-        self.anergy = self.gate.step(
-            self.anergy,
-            stimulus=jnp.asarray((self._blown > 0) | will_blow, jnp.float32),
-            costimulus=jnp.asarray(self._ok > 0, jnp.float32),
-            il2=il2)
-        self._blown[:] = 0.0
-        self._ok[:] = 0.0
+        with span(IMMUNE):
+            stim = jnp.asarray(admitted / max(self.ecfg.num_slots, 1),
+                               jnp.float32)
+            self.reg_state = self.regulator.step(self.reg_state, stim)
+            il2 = 1.0 if queue_len < self.ecfg.low_pressure \
+                * self.ecfg.num_slots else 0.0
+            will_blow = (queued_demand > 0) & \
+                (predicted_cost > self.ecfg.latency_budget)
+            self.anergy = self.gate.step(
+                self.anergy,
+                stimulus=jnp.asarray((self._blown > 0) | will_blow,
+                                     jnp.float32),
+                costimulus=jnp.asarray(self._ok > 0, jnp.float32),
+                il2=il2)
+            self._blown[:] = 0.0
+            self._ok[:] = 0.0
 
     # -- durability ----------------------------------------------------------
     def export_state(self) -> dict:
@@ -715,7 +731,7 @@ class Engine:
         #                                    (replay re-walks the same tokens)
         self._spec_cache = None
         if self.ecfg.capture_logits and not req.out_tokens:
-            req.out_logits.append(np.asarray(logits)[0, -1].copy())
+            req.out_logits.append(pull(logits)[0, -1].copy())
         return _seed_token(logits, spec_for([req.params]),
                            do_sample=not req.params.is_greedy)
 
@@ -723,22 +739,23 @@ class Engine:
         """Record the prefill-seeded first token. A request resuming from
         preemption already holds its history — the seed (bitwise identical by
         the fold-index discipline) is re-derived, not re-recorded."""
+        tok = int(pull(first)[0, 0])
         if req.params.has_penalties:
             # the seed draw itself saw zero counts (both backends agree); the
             # seed token is counted from the next draw on — replay included,
             # since the re-derived seed is bitwise the recorded one
-            self.tok_counts[req.slot, int(first[0, 0])] += 1
+            self.tok_counts[req.slot, tok] += 1
         if req.out_tokens:
             self.replayed_tokens += 1
             req.replayed_tokens += 1
             return
-        req.out_tokens.append(int(first[0, 0]))
+        req.out_tokens.append(tok)
         if req.params.logprobs:
             req.out_logprobs.append(
-                float(np.asarray(_chosen_lp(logits, first))[0, 0]))
+                float(pull(_chosen_lp(logits, first))[0, 0]))
             tv, ti = _topk_lp(logits, req.params.logprobs)
-            req.out_topk.append(([int(x) for x in np.asarray(ti)[0]],
-                                 [float(x) for x in np.asarray(tv)[0]]))
+            req.out_topk.append(([int(x) for x in pull(ti)[0]],
+                                 [float(x) for x in pull(tv)[0]]))
 
     # -- paging --------------------------------------------------------------
     def _chunkable(self, req: ServeRequest) -> bool:
@@ -864,6 +881,7 @@ class Engine:
         req.slot = slot
         if req.admit_tick < 0:
             req.admit_tick = self.tick
+            req.admit_time = time.perf_counter()
         if req.preempt_tick >= 0:          # resuming after preemption
             req.requeue_ticks += self.tick - req.preempt_tick
             req.preempt_tick = -1
@@ -918,7 +936,7 @@ class Engine:
         deterministic."""
         over = 1.0 if self._over_budget_now(req) else 0.0
         if self.admission is not None:
-            anergy = float(self.admission.anergy.level[req.rclass])
+            anergy = self.admission.anergy_level(req.rclass)
             cost = self.admission.remembered_cost(req.rclass)
         else:
             anergy = cost = 0.0
@@ -1136,7 +1154,8 @@ class Engine:
         running requests have already revealed (ticks held so far is a lower
         bound on their class's true cost). Without the reveal, the cold-start
         memory is all zeros and the first burst of heavies convoys the pool."""
-        cost = np.asarray(self.admission.memory.value, np.float64).copy()
+        with span(IMMUNE):
+            cost = pull(self.admission.memory.value).astype(np.float64)
         for r in self.slots:
             if r is not None:
                 cost[r.rclass] = max(cost[r.rclass], self.tick - r.admit_tick)
@@ -1343,11 +1362,11 @@ class Engine:
             return_logits=self.ecfg.capture_logits,
             return_logprobs=want_lp, use_penalties=use_penalties,
             return_topk=want_k)
-        nxt_host = np.asarray(nxt[:, 0])
-        ok_host = np.asarray(ok)
-        lg_host = np.asarray(logits[:, -1]) if logits is not None else None
-        lp_host = np.asarray(lps[:, 0]) if lps is not None else None
-        tv_host, ti_host = (np.asarray(topk[0]), np.asarray(topk[1])) \
+        nxt_host = pull(nxt)[:, 0]
+        ok_host = pull(ok)
+        lg_host = pull(logits)[:, -1] if logits is not None else None
+        lp_host = pull(lps)[:, 0] if lps is not None else None
+        tv_host, ti_host = (pull(topk[0]), pull(topk[1])) \
             if topk is not None else (None, None)
         bad: list[int] = []
         for slot, req in enumerate(self.slots):
@@ -1395,11 +1414,11 @@ class Engine:
             attn_backend=self.ecfg.attn_backend,
             return_logits=self.ecfg.capture_logits,
             router_bias=self.router_bias)
-        drafts_h = np.asarray(drafts)
-        am_h = np.asarray(am)
-        ok_h = np.asarray(ok)
-        lg_h = np.asarray(logits) if logits is not None else None
-        last_h = np.array(self.last)          # writable copy
+        drafts_h = pull(drafts)
+        am_h = pull(am)
+        ok_h = pull(ok)
+        lg_h = pull(logits) if logits is not None else None
+        last_h = pull(self.last).copy()       # writable copy
         self.spec_ticks += 1
         bad: list[int] = []
         for slot, req in enumerate(self.slots):
@@ -1445,13 +1464,16 @@ class Engine:
     def step(self):
         """One engine tick: admit into free slots, land a prefill chunk, decode
         one token for every active slot, retire finished sequences, advance the
-        immune states. Runs on the engine's device."""
-        with jax.default_device(self.device):
+        immune states. Runs on the engine's device, inside a ``STEP`` span
+        that holds one span per phase (``serve.spans``)."""
+        with jax.default_device(self.device), span(STEP):
             self._tick()
 
     def _tick(self):
-        self._admit()
-        self._prefill_tick()
+        with span(ADMIT):
+            self._admit()
+        with span(PREFILL):
+            self._prefill_tick()
         self.concurrency_hw = max(self.concurrency_hw,
                                   sum(r is not None for r in self.slots))
         # sample only when a resident request asks to: both do_sample variants
@@ -1473,24 +1495,28 @@ class Engine:
         use_spec = self._spec_ok and not do_sample and not want_lp
         page = self.ecfg.page_size
         lookahead = self.ecfg.spec_decode if use_spec else 0
-        for slot in np.flatnonzero(self.active_host):
-            slot = int(slot)
-            if not self.active_host[slot]:
-                continue              # preempted by an earlier slot's growth
-            # decode writes at pos (a spec tick at pos..pos+k, clamped to the
-            # slot's logical capacity — writes past it route to the null page
-            # and belong to tokens the budget check never emits): append pages
-            # lazily at the boundary, preempting the lowest-priority resident
-            # if the pool is dry
-            cover = min(int(self.pos_host[slot]) + 1 + lookahead,
-                        self.ecfg.max_cache)
-            self._acquire(slot, pages_for(cover, page))
+        with span(PAGES):
+            for slot in np.flatnonzero(self.active_host):
+                slot = int(slot)
+                if not self.active_host[slot]:
+                    continue          # preempted by an earlier slot's growth
+                # decode writes at pos (a spec tick at pos..pos+k, clamped to
+                # the slot's logical capacity — writes past it route to the
+                # null page and belong to tokens the budget check never
+                # emits): append pages lazily at the boundary, preempting the
+                # lowest-priority resident if the pool is dry
+                cover = min(int(self.pos_host[slot]) + 1 + lookahead,
+                            self.ecfg.max_cache)
+                self._acquire(slot, pages_for(cover, page))
         if self.active_host.any():
-            if use_spec:
-                self._spec_step()
-            else:
-                self._plain_step(do_sample, use_penalties, want_lp, want_k)
-        self._retire()
+            with span(DECODE):
+                if use_spec:
+                    self._spec_step()
+                else:
+                    self._plain_step(do_sample, use_penalties, want_lp,
+                                     want_k)
+        with span(RETIRE):
+            self._retire()
         if self.admission is not None:
             demand = np.zeros(self.ecfg.num_classes, np.float64)
             for r in self.queue:
@@ -1640,7 +1666,6 @@ class Engine:
             "throughput": toks / max(self.tick, 1),
             "p50_latency": float(np.percentile(lat, 50)) if lat.size else empty,
             "p99_latency": float(np.percentile(lat, 99)) if lat.size else empty,
-            "max_latency": float(lat.max()) if lat.size else empty,
             # fraction of total demand served within the latency budget: shed
             # and rejected requests count against goodput — rejection is not a
             # free lunch
@@ -1680,8 +1705,6 @@ class Engine:
             "replayed_tokens": self.replayed_tokens,
             # request-facing API telemetry: wall-clock latency over
             # completions (ms) and how much of the traffic asked to sample
-            "p50_wall_ms": float(np.percentile(wall, 50)) if wall.size
-            else empty,
             "p99_wall_ms": float(np.percentile(wall, 99)) if wall.size
             else empty,
             "sampled_requests": sum(1 for r in self.completed

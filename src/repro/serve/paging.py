@@ -83,6 +83,7 @@ from typing import Optional
 import numpy as np
 
 from ..core import immune
+from .spans import pull
 
 NULL_PAGE = 0
 
@@ -420,7 +421,7 @@ class PageAllocator:
         v = self.pin_memory.value
         self.pin_memory = self.pin_memory.update(
             v.at[self._rc(rclass)].set(float(npages)))
-        self._class_w = np.asarray(self.pin_memory.value)
+        self._class_w = pull(self.pin_memory.value)
 
     def _score(self, page: int) -> tuple:
         """Eviction ordering: coldest class first, then least recently used."""

@@ -744,7 +744,6 @@ class Router:
             "throughput": toks / max(self.tick, 1),
             "p50_latency": float(np.percentile(lat, 50)) if lat.size else empty,
             "p99_latency": float(np.percentile(lat, 99)) if lat.size else empty,
-            "max_latency": float(lat.max()) if lat.size else empty,
             "goodput": in_budget / max(demand, 1),
             # placement telemetry: where traffic landed and why
             "placements": [int(c) for c in place],
@@ -773,7 +772,6 @@ class Router:
                 "recovered_finished": len(self.recovered),
                 "recovered_open": self.recovered_open,
                 "recovered_pinned_pages": self.recovered_pages,
-                "dedup_drops": self.dedup_drops,
                 "snapshots": self.snapshots,
             },
             # slot-group telemetry
