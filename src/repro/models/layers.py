@@ -368,25 +368,119 @@ def init_attention_cache(cfg: ModelConfig, batch: int, s_max: int, dtype) -> dic
 # ---------------------------------------------------------------------------
 # paged attention (block-table KV cache — the serving engine's memory plane)
 # ---------------------------------------------------------------------------
+# A layer's pool stores, for each KV head, page p as ONE contiguous row of
+# page*D values ((page, D) row-major): K/V as (Hkv, P, page*D). A
+# (P, page, Hkv, D) pool would tile its two minor dims (Hkv, D) on the TPU's
+# (8, 128) tiles — (5, 64) for smollm-360m pads 6x — so the compiler stores it
+# page-index-minor, and every gather by page first re-lays the whole pool
+# out. A row pads at most its one minor dim and is gathered as it lies; the
+# KV head leads so that a gather by (head, page) lands each head's pages in
+# sequence order, the (B, Hkv, S, D) the attention contraction reads, where
+# a row of all heads would need a transpose of every gathered page.
+#
+# The attention functions below read a layer's pool and never write it: each
+# returns the K/V of its own positions, which it lays over the gathered pages
+# for its attention, and the stack writes every layer's new K/V once, after
+# its layer scan (``write_pages``) — so no scan carries a pool. A layer sees
+# its cache as ``{'k','v': (L, Hkv, P, page*D) the stack's pools, 'layer':
+# index}`` and gathers by (layer, head, page) from the whole stack: slicing
+# its layer out would copy it.
 def init_attention_cache_paged(cfg: ModelConfig, num_pages: int, page_size: int,
                                dtype) -> dict:
-    """Physical page pool for one attention layer: K/V as (P, page, Hkv, D).
-    Page 0 is the null/trash page (see ``serve.paging``)."""
-    hk, hd = cfg.num_kv_heads, cfg.head_dim
-    return {"k": jnp.zeros((num_pages, page_size, hk, hd), dtype),
-            "v": jnp.zeros((num_pages, page_size, hk, hd), dtype)}
+    """Physical page pool for one attention layer: K/V as (Hkv, P, page*D),
+    one row per KV head and page. Page 0 is the null/trash page (see
+    ``serve.paging``)."""
+    shape = (cfg.num_kv_heads, num_pages, page_size * cfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _gather_pages(pages: Array, table: Array) -> Array:
-    """pages: (P, page, Hkv, D); table: (..., maxp) -> (..., maxp*page, Hkv, D).
+def page_view(pages: Array, cfg: ModelConfig) -> Array:
+    """(..., Hkv, P, page*D) rows -> the (..., P, page, Hkv, D) page view."""
+    rows = pages.reshape(*pages.shape[:-1], -1, cfg.head_dim)
+    return jnp.moveaxis(rows, -4, -2)
+
+
+def _gather_pages(pages: Array, layer: Array, table: Array,
+                  cfg: ModelConfig) -> Array:
+    """pages: (L, Hkv, P, page*D); table: (B, maxp) -> layer ``layer``'s
+    (B, maxp*page, Hkv, D).
 
     The gathered sequence is the slot's cache in logical order, padded by null
     pages to exactly maxp*page positions — when maxp*page == max_cache this is
     the same K/V tensor (values *and* shape) the dense slot-row layout holds,
     so the masked softmax downstream is bitwise identical to the unpaged path.
     """
-    g = pages[table]                                   # (..., maxp, page, Hk, D)
-    return g.reshape(*table.shape[:-1], -1, *pages.shape[2:])
+    b, hk = table.shape[0], pages.shape[1]
+    g = pages[layer, jnp.arange(hk)[None, :, None], table[:, None, :]]
+    # the barrier hands the attention contraction this (B, S, Hkv, D) tensor
+    # as the dense slot-row path does: folded into the contraction instead,
+    # the head transpose changes its summation order on some backends
+    return jax.lax.optimization_barrier(
+        g.reshape(b, hk, -1, cfg.head_dim).transpose(0, 2, 1, 3))
+
+
+def _overlay(g: Array, new: Array, p0: Array) -> Array:
+    """Lay ``new`` (B, T, Hkv, D), the K or V of positions p0..p0+T-1 of
+    each lane (``p0`` (B,)), over the gathered ``g`` (B, S, Hkv, D): the
+    tensor the pool would gather had those positions been written first."""
+    t = new.shape[1]
+    rel = jnp.arange(g.shape[1])[None, :] - p0[:, None]          # (B, S)
+    inside = ((rel >= 0) & (rel < t))[:, :, None, None]
+    if t > 1:
+        new = jnp.take_along_axis(
+            new, jnp.clip(rel, 0, t - 1)[:, :, None, None], axis=1)
+    return jnp.where(inside, new, g)
+
+
+def _paged_kv(cache: dict, table: Array, k: Array, v: Array, p0: Array,
+              cfg: ModelConfig):
+    """The layer's gathered (B, S, Hkv, D) K and V with ``k``/``v`` (B, T,
+    Hkv, D), the K/V of positions p0..p0+T-1, laid in."""
+    return tuple(_overlay(_gather_pages(cache[n], cache["layer"], table, cfg),
+                          new, p0) for n, new in (("k", k), ("v", v)))
+
+
+def _page_views(cache: dict, cfg: ModelConfig):
+    """The layer's K and V pools as (P, page, Hkv, D) page views: what the
+    Pallas kernels read."""
+    return tuple(page_view(cache[n][cache["layer"]], cfg) for n in ("k", "v"))
+
+
+def write_pages(pages: Array, new: Array, table: Array, p0: Array,
+                active: Optional[Array] = None) -> Array:
+    """Write every layer's K or V of T consecutive positions per lane into
+    the stacked row pool, as one read-modify-write of the touched rows.
+
+    pages: (L, Hkv, P, page*D); new: (L, B, T, Hkv, D), lane b's rows at
+    positions p0[b]..p0[b]+T-1; table: (B, maxp). Positions past the table
+    and every position of a lane that ``active`` (B,) marks False are not
+    written; a touched page that takes no write is routed to the null page,
+    which absorbs the rewrite, so only pages a lane owns are changed.
+    """
+    n_l, b, t, hk, hd = new.shape
+    page = pages.shape[-1] // hd
+    maxp = table.shape[1]
+    n = (t + page - 2) // page + 1                     # pages T positions span
+    lpage = p0[:, None] // page + jnp.arange(n)[None, :]          # (B, n)
+    rel = (lpage[:, :, None] * page + jnp.arange(page)
+           - p0[:, None, None]).reshape(b, n * page)   # index into new
+    hit = (rel >= 0) & (rel < t)
+    if active is not None:
+        hit &= active[:, None]
+    hit = hit.reshape(b, n, page) & (lpage < maxp)[:, :, None]
+    phys = jnp.take_along_axis(table, jnp.clip(lpage, 0, maxp - 1), axis=1)
+    phys = jnp.where(hit.any(axis=2), phys, 0).reshape(1, 1, b * n)
+    # index rows by (layer, head, page): a scatter over pages alone, with the
+    # other dims in its window, makes the compiler re-lay the pool out for it
+    layer = jnp.arange(n_l)[:, None, None]
+    head = jnp.arange(hk)[None, :, None]
+    vals = jnp.take_along_axis(
+        jnp.moveaxis(new, 3, 1),                       # (L, Hkv, B, T, D)
+        jnp.clip(rel, 0, t - 1)[None, None, :, :, None], axis=3)
+    rows = pages[layer, head, phys].reshape(n_l, hk, b, n, page, hd)
+    rows = jnp.where(hit[None, None, :, :, :, None],
+                     vals.reshape(n_l, hk, b, n, page, hd), rows)
+    return pages.at[layer, head, phys].set(rows.reshape(n_l, hk, b * n, -1))
 
 
 def attention_decode_paged(params, x: Array, cfg: ModelConfig, cache: dict,
@@ -394,41 +488,43 @@ def attention_decode_paged(params, x: Array, cfg: ModelConfig, cache: dict,
                            backend: str = "xla"):
     """One-token decode against a paged KV cache.
 
-    cache: {'k','v': (P, page, Hkv, D)} physical page pools; ``pos`` (B,) is each
-    slot's cache position; ``table`` (B, maxp) the block table; ``active`` (B,)
-    routes the writes of inactive slots to the null page so a garbage lane can
-    never dirty a page a mid-prefill slot already owns.
+    cache: the layer's view of the stack's page pools, read only (above);
+    ``pos`` (B,) is each slot's cache position; ``table`` (B, maxp) the block
+    table; ``active`` (B,) marks the lanes whose write the stack makes
+    (inactive lanes write nothing, so a garbage lane can never dirty a page a
+    mid-prefill slot already owns). Returns (out, {'k','v': (B, 1, Hkv, D)}):
+    the new token's K/V, which ``write_pages`` puts at ``pos`` after the scan.
 
     ``backend`` picks the attention compute: ``"xla"`` gathers the pages into a
-    dense (B, maxp*page, ...) K/V and runs the masked-softmax oracle (bitwise
-    the dense slot-row path); ``"pallas"`` / ``"pallas_interpret"`` run the
-    ``kernels.paged_attention`` scalar-prefetch kernel instead — the block
-    table becomes the DMA schedule and no contiguous K/V tensor ever exists.
-    Writes are identical either way, so the backends can be swapped mid-stream.
+    dense (B, maxp*page, ...) K/V with the new token laid in at ``pos`` and
+    runs the masked-softmax oracle (bitwise the dense slot-row path);
+    ``"pallas"`` / ``"pallas_interpret"`` run the ``kernels.paged_attention``
+    scalar-prefetch kernel on the (P, page, Hkv, D) view with this tick's
+    writes made — the block table becomes the DMA schedule and no contiguous
+    K/V tensor ever exists. Both see the same K/V, so the backends can be
+    swapped mid-stream.
     """
     b = x.shape[0]
     pos = jnp.asarray(pos)
     q, k, v = _qkv(params, x, cfg, pos[:, None])
-    page = cache["k"].shape[1]
-    rows = jnp.arange(b)
-    pidx = jnp.where(active, table[rows, pos // page], 0)
-    off = pos % page
-    ck = cache["k"].at[pidx, off].set(k[:, 0])
-    cv = cache["v"].at[pidx, off].set(v[:, 0])
     if backend != "xla":
         # deferred import: layers must stay importable without the kernel pkg
         from ..kernels.paged_attention import paged_attention as paged_kernel
-        out = paged_kernel(q[:, 0], ck, cv, table,
+        ck, cv = _page_views(cache, cfg)
+        page = ck.shape[1]
+        pidx = jnp.where(active, table[jnp.arange(b), pos // page], 0)
+        off = pos % page
+        out = paged_kernel(q[:, 0], ck.at[pidx, off].set(k[:, 0]),
+                           cv.at[pidx, off].set(v[:, 0]), table,
                            (pos + 1).astype(jnp.int32),
                            interpret=backend == "pallas_interpret")
         out = out.reshape(b, 1, -1).astype(v.dtype)
     else:
-        gk = _gather_pages(ck, table)                  # (B, maxp*page, Hk, D)
-        gv = _gather_pages(cv, table)
+        gk, gv = _paged_kv(cache, table, k, v, pos, cfg)
         kpos = jnp.arange(gk.shape[1])[None, :]
         mask = kpos <= pos[:, None]                    # (B, S)
         out = _sdpa(q, gk, gv, mask[:, None, :], cfg)
-    return out @ params["wo"], {"k": ck, "v": cv}
+    return out @ params["wo"], {"k": k, "v": v}
 
 
 def attention_verify_paged(params, x: Array, cfg: ModelConfig, cache: dict,
@@ -439,11 +535,12 @@ def attention_verify_paged(params, x: Array, cfg: ModelConfig, cache: dict,
 
     x: (B, Sq, D) — row 0 is the slot's last emitted token at cache position
     ``pos`` (exactly what the next decode tick would feed), rows 1..Sq-1 the
-    draft tokens at ``pos+1..pos+Sq-1``. Every row writes its K/V at its own
-    position (inactive slots and positions past the block table route to the
-    null page) and attends causally up to itself — per-row this is bitwise
-    the computation ``attention_decode_paged`` would run at that position
-    with that K/V prefix resident, which is the whole accept-oracle argument.
+    draft tokens at ``pos+1..pos+Sq-1``. Every row attends causally up to
+    itself over the pages with the rows' own K/V laid in, and returns that
+    K/V (B, Sq, Hkv, D) for the stack to write (inactive slots and positions
+    past the block table are not written) — per-row this is bitwise the
+    computation ``attention_decode_paged`` would run at that position with
+    that K/V prefix resident, which is the whole accept-oracle argument.
     Positions the accept loop rejects hold draft K/V afterwards; they are
     only ever read masked and are overwritten by the next tick's writes
     before becoming visible.
@@ -452,56 +549,46 @@ def attention_verify_paged(params, x: Array, cfg: ModelConfig, cache: dict,
     pos = jnp.asarray(pos)
     lpos = pos[:, None] + jnp.arange(sq)[None, :]       # (B, Sq) absolute
     q, k, v = _qkv(params, x, cfg, lpos)
-    page = cache["k"].shape[1]
-    maxp = table.shape[1]
-    # writes: each row lands at its own position; inactive lanes and rows
-    # past the table's capacity go to the null/trash page
-    writable = active[:, None] & (lpos < maxp * page)
-    pidx = jnp.take_along_axis(table, jnp.clip(lpos // page, 0, maxp - 1),
-                               axis=1)
-    pidx = jnp.where(writable, pidx, 0)
-    off = lpos % page
-    ck = cache["k"].at[pidx, off].set(k)
-    cv = cache["v"].at[pidx, off].set(v)
     if backend != "xla":
         # deferred import: layers must stay importable without the kernel pkg
         from ..kernels.paged_attention import paged_attention_verify
-        out = paged_attention_verify(q, ck, cv, table,
+        ck, cv = _page_views(cache, cfg)
+        page, maxp = ck.shape[1], table.shape[1]
+        # each row lands at its own position; inactive lanes and rows past
+        # the table's capacity go to the null/trash page
+        writable = active[:, None] & (lpos < maxp * page)
+        pidx = jnp.take_along_axis(table, jnp.clip(lpos // page, 0, maxp - 1),
+                                   axis=1)
+        pidx = jnp.where(writable, pidx, 0)
+        off = lpos % page
+        out = paged_attention_verify(q, ck.at[pidx, off].set(k),
+                                     cv.at[pidx, off].set(v), table,
                                      pos.astype(jnp.int32),
                                      interpret=backend == "pallas_interpret")
         out = out.reshape(b, sq, -1).astype(v.dtype)
     else:
-        gk = _gather_pages(ck, table)                  # (B, maxp*page, Hk, D)
-        gv = _gather_pages(cv, table)
+        gk, gv = _paged_kv(cache, table, k, v, pos, cfg)
         kpos = jnp.arange(gk.shape[1])[None, None, :]
         mask = kpos <= lpos[:, :, None]                # (B, Sq, S)
         out = _sdpa(q, gk, gv, mask, cfg)
-    return out @ params["wo"], {"k": ck, "v": cv}
+    return out @ params["wo"], {"k": k, "v": v}
 
 
 def attention_prefill_paged(params, x: Array, cfg: ModelConfig, cache: dict,
                             table_row: Array, p0: Array):
-    """One prefill *chunk* (batch-of-1) written straight into the slot's pages.
+    """One prefill *chunk* (batch-of-1) against the slot's pages.
 
     x: (1, C, D) — the chunk's embeddings; ``table_row`` (maxp,) the slot's
     block table row; ``p0`` the chunk's first absolute position. Queries attend
-    over the gathered pages (fixed maxp*page == max_cache length), so every
-    chunk call compiles one shape regardless of prompt length — and, because
-    padded/garbage positions are masked to exact zeros, the result is bitwise
-    identical to the one-shot full-sequence prefill.
+    over the gathered pages (fixed maxp*page == max_cache length) with the
+    chunk's K/V laid in, so every chunk call compiles one shape regardless of
+    prompt length — and, because padded/garbage positions are masked to exact
+    zeros, the result is bitwise identical to the one-shot full-sequence
+    prefill. Returns (out, {'k','v': (1, C, Hkv, D)}), the chunk's K/V for
+    the stack to write.
     """
-    _, c, _ = x.shape
-    lpos = p0 + jnp.arange(c)                          # absolute positions
-    q, k, v = _qkv(params, x, cfg, lpos[None, :])
-    page = cache["k"].shape[1]
-    ck = cache["k"].at[table_row[lpos // page], lpos % page].set(k[0])
-    cv = cache["v"].at[table_row[lpos // page], lpos % page].set(v[0])
-    gk = _gather_pages(ck, table_row)[None]            # (1, maxp*page, Hk, D)
-    gv = _gather_pages(cv, table_row)[None]
-    kpos = jnp.arange(gk.shape[1])[None, :]
-    mask = (kpos <= lpos[:, None])[None]               # (1, C, S)
-    out = _sdpa(q, gk, gv, mask, cfg)
-    return out @ params["wo"], {"k": ck, "v": cv}
+    return attention_prefill_paged_multi(params, x, cfg, cache,
+                                         table_row[None], jnp.reshape(p0, (1,)))
 
 
 def attention_prefill_paged_multi(params, x: Array, cfg: ModelConfig,
@@ -515,22 +602,16 @@ def attention_prefill_paged_multi(params, x: Array, cfg: ModelConfig,
     whose all-null tables route every write to the null/trash page. Each lane's
     math is row-independent and shape-identical to the single-job path, so
     batching jobs costs no exactness — it just turns J prefill dispatches per
-    tick into one.
+    tick into one. Returns (out, {'k','v': (J, C, Hkv, D)}).
     """
     j, c, _ = x.shape
     lpos = p0s[:, None] + jnp.arange(c)[None, :]       # (J, C) absolute
     q, k, v = _qkv(params, x, cfg, lpos)
-    page = cache["k"].shape[1]
-    pidx = jnp.take_along_axis(tables, lpos // page, axis=1)   # (J, C)
-    off = lpos % page
-    ck = cache["k"].at[pidx, off].set(k)
-    cv = cache["v"].at[pidx, off].set(v)
-    gk = _gather_pages(ck, tables)                     # (J, maxp*page, Hk, D)
-    gv = _gather_pages(cv, tables)
+    gk, gv = _paged_kv(cache, tables, k, v, p0s, cfg)
     kpos = jnp.arange(gk.shape[1])[None, None, :]
     mask = kpos <= lpos[:, :, None]                    # (J, C, S)
     out = _sdpa(q, gk, gv, mask, cfg)
-    return out @ params["wo"], {"k": ck, "v": cv}
+    return out @ params["wo"], {"k": k, "v": v}
 
 
 # ---------------------------------------------------------------------------

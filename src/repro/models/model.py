@@ -223,9 +223,9 @@ def decode_step(params, cfg: ModelConfig, batch: dict, cache: dict,
     ``active`` (B,) additionally freezes the *slot-row* caches (recurrent
     state, ring buffers) of inactive slots: a garbage lane must never advance
     state a chunked prefill is threading through that row between ticks. The
-    paged leaves don't need the freeze — inactive writes are routed to the
-    null page inside ``attention_decode_paged``. ``attn_backend`` picks the
-    paged attention compute: ``"xla"`` (dense gather oracle) or
+    paged leaves don't need the freeze — inactive lanes write no page
+    (``layers.write_pages``). ``attn_backend`` picks the paged attention
+    compute: ``"xla"`` (dense gather oracle) or
     ``"pallas"`` / ``"pallas_interpret"`` (the block-table Pallas kernel)."""
     x = _embed(params, cfg, batch["token"])
     if cfg.family == "audio":
@@ -278,9 +278,10 @@ def verify_step(params, cfg: ModelConfig, batch: dict, pool: dict,
 def init_slot_cache_paged(cfg: ModelConfig, num_slots: int, s_max: int,
                           num_pages: int, page_size: int) -> dict:
     """Paged pooled decode cache: full-attention K/V live in per-layer physical
-    page pools (num_pages, page_size, Hkv, D) indexed by a host-side block
-    table; recurrent/ring leaves stay slot-indexed. ``pos`` is per-slot — like
-    ``init_cache`` but a (num_slots,) vector, so slots can sit at different
+    page pools (Hkv, num_pages, page_size*D), one row per KV head and page,
+    indexed by a host-side block table; recurrent/ring leaves stay
+    slot-indexed. ``pos`` is per-slot — like ``init_cache`` but a
+    (num_slots,) vector, so slots can sit at different
     depths of their own sequences while sharing one compiled decode step. (The
     pre-paging fixed-row layout is the degenerate page_size == s_max config.)"""
     return {"layers": transformer.init_stack_cache_paged(
@@ -292,19 +293,20 @@ def insert_slot_cache_paged(pool: dict, one: dict, cfg: ModelConfig,
                             slot: Array, table_row: Array) -> dict:
     """Splice a one-shot prefilled batch-of-1 dense cache into the paged pool.
 
-    Full-attention leaves: the dense (1, s_max, ...) row is reshaped to
-    (maxp, page, ...) and scattered to the slot's physical pages; rows beyond
-    the slot's allocation land on the null page (table entries there point at
-    it), which is by construction write-don't-care. Other leaves are whole-row
-    copies at the slot's batch index, erasing any stale state from the slot's
-    previous occupant."""
+    Full-attention leaves: the dense (1, s_max, Hkv, D) row is cut into
+    (Hkv, maxp, page*D) page rows and scattered to the slot's physical pages;
+    rows beyond the slot's allocation land on the null page (table entries
+    there point at it), which is by construction write-don't-care. Other
+    leaves are whole-row copies at the slot's batch index, erasing any stale
+    state from the slot's previous occupant."""
     def splice(kind, full_d, one_d):
         if kind in ("attn", "moe"):
             def pagewise(full, o):
-                reps, page = full.shape[0], full.shape[2]
-                chunks = o.astype(full.dtype).reshape(
-                    reps, -1, page, *full.shape[3:])
-                return full.at[:, table_row].set(chunks)
+                reps, hk, _, row = full.shape
+                rows = o.astype(full.dtype).reshape(reps, -1, row // o.shape[-1],
+                                                    hk, o.shape[-1])
+                rows = jnp.moveaxis(rows, 3, 1).reshape(reps, hk, -1, row)
+                return full.at[:, :, table_row].set(rows)
             return jax.tree.map(pagewise, full_d, one_d)
         return jax.tree.map(
             lambda full, o: jax.lax.dynamic_update_slice_in_dim(
@@ -379,8 +381,8 @@ def copy_page_paged(pool: dict, cfg: ModelConfig, src: Array, dst: Array) -> dic
     read masked (or overwritten by the fork owner's own writes)."""
     def cp(kind, full_d):
         if kind in ("attn", "moe"):
-            return jax.tree.map(lambda full: full.at[:, dst].set(full[:, src]),
-                                full_d)
+            return jax.tree.map(
+                lambda full: full.at[:, :, dst].set(full[:, :, src]), full_d)
         return full_d
 
     return {"layers": transformer.map_block_caches(cfg, cp, pool["layers"]),

@@ -101,9 +101,11 @@ def apply_block_decode(p, x: Array, cfg: ModelConfig, kind: str, cache, pos,
                        attn_backend: str = "xla"):
     """One-token block step. Returns (x, new_cache, moe_stats | None).
     ``table``/``active`` switch full-attention layers onto the paged KV path
-    (serving engine); sliding-window and recurrent layers keep their slot-row
-    caches either way. ``attn_backend`` selects the paged attention compute
-    (XLA gather oracle vs the Pallas block-table kernel)."""
+    (serving engine): ``cache`` is then the layer's page pool, read only, and
+    ``new_cache`` the token's K/V, which the stack writes after its layer
+    scan. Sliding-window and recurrent layers keep their slot-row caches
+    either way. ``attn_backend`` selects the paged attention compute (XLA
+    gather oracle vs the Pallas block-table kernel)."""
     stats = None
     h = rmsnorm(p["norm1"], x, cfg)
     if kind in ("attn", "moe"):
@@ -142,7 +144,8 @@ def apply_block_verify(p, x: Array, cfg: ModelConfig, kind: str, cache,
     so every (lane, position) row is independent — per row this is bitwise the
     ``apply_block_decode`` computation at that position. Recurrent kinds have
     cross-position state and are not verifiable in one batched step; the
-    engine gates speculative decoding to attention stacks."""
+    engine gates speculative decoding to attention stacks. Returns the rows'
+    K/V in place of a new cache, for the stack to write."""
     if kind not in ("attn", "moe"):
         raise NotImplementedError(f"verify step unsupported for {kind!r}")
     stats = None
@@ -191,10 +194,11 @@ def apply_block_prefill(p, x: Array, cfg: ModelConfig, kind: str, cache,
 def apply_block_prefill_chunk(p, x: Array, cfg: ModelConfig, kind: str, cache,
                               table_row: Array, p0: Array,
                               bias: Optional[Array] = None):
-    """One prefill-chunk block step against the paged pool (full attention) or
-    the slot's recurrent state row (SSM). Sliding-window and RG-LRU layers are
-    not chunkable (ring-slot remapping / associative-scan splits change the
-    numerics) — the engine routes those configs to one-shot prefill."""
+    """One prefill-chunk block step against the paged pool (full attention:
+    returns the chunk's K/V for the stack to write) or the slot's recurrent
+    state row (SSM). Sliding-window and RG-LRU layers are not chunkable
+    (ring-slot remapping / associative-scan splits change the numerics) — the
+    engine routes those configs to one-shot prefill."""
     stats = None
     h = rmsnorm(p["norm1"], x, cfg)
     if kind in ("attn", "moe"):
@@ -219,7 +223,8 @@ def apply_block_prefill_chunk_multi(p, x: Array, cfg: ModelConfig, kind: str,
                                     bias: Optional[Array] = None):
     """J concurrent prefill-chunk block steps against the paged pool in one
     call — attention-stack kinds only (attn/moe carry no slot-row cache, so
-    lanes are fully independent; recurrent kinds stay on the one-job path)."""
+    lanes are fully independent; recurrent kinds stay on the one-job path).
+    Returns the lanes' K/V for the stack to write."""
     if kind not in ("attn", "moe"):
         raise NotImplementedError(f"batched chunk prefill unsupported for "
                                   f"{kind!r}")
@@ -363,6 +368,33 @@ def init_stack_cache_paged(cfg: ModelConfig, num_slots: int, s_max: int,
     return out
 
 
+def _scan_paged(pattern, seg_cache: list) -> list:
+    """What a layer scan takes of a segment's caches: a page pool stays whole
+    (closed over; the scan takes its layer index and each layer gathers from
+    the stack by (layer, page) — ``layers._gather_pages``), a slot-row cache
+    is sliced per layer."""
+    return [jnp.arange(cs["k"].shape[0]) if kind in ("attn", "moe") else cs
+            for kind, cs in zip(pattern, seg_cache)]
+
+
+def _layer_cache(kind: str, whole, scanned):
+    """One layer's cache inside a scan over ``_scan_paged`` inputs."""
+    if kind in ("attn", "moe"):
+        return {"k": whole["k"], "v": whole["v"], "layer": scanned}
+    return scanned
+
+
+def _write_paged(pattern, seg_cache: list, scanned: list, table: Array,
+                 p0: Array, active: Optional[Array] = None) -> list:
+    """A segment's caches after a layer scan that read its page pools: each
+    full-attention position's scan output is its layers' new K/V, written
+    into the pool here, once (``layers.write_pages``); every other position's
+    scan output is its new slot-row cache."""
+    return [{n: layers.write_pages(cs[n], out[n], table, p0, active)
+             for n in ("k", "v")} if kind in ("attn", "moe") else out
+            for kind, cs, out in zip(pattern, seg_cache, scanned)]
+
+
 def apply_stack_prefill(stack_params: list, x: Array, cfg: ModelConfig, caches: list,
                         bias: Optional[Array] = None,
                         prefix_len: Optional[Array] = None):
@@ -399,8 +431,10 @@ def apply_stack_decode(stack_params: list, x: Array, cfg: ModelConfig, caches: l
                        active: Optional[Array] = None,
                        attn_backend: str = "xla"):
     """One-token pass. Returns (x, new_caches). ``table``/``active`` select the
-    paged KV path for full-attention layers (closed over, same for every layer);
-    ``attn_backend`` picks its compute (XLA gather vs Pallas kernel). The bias
+    paged KV path for full-attention layers (closed over, same for every layer):
+    the scan reads the page pools and emits each layer's new K/V, written into
+    the pools once after it, so no scan carries a pool. ``attn_backend``
+    picks its compute (XLA gather vs Pallas kernel). The bias
     rows scanned follow the *params'* repetition depth, not the config's, so a
     ``truncate_stack`` draft slice takes the leading layers' bias rows."""
     li = 0
@@ -414,20 +448,27 @@ def apply_stack_decode(stack_params: list, x: Array, cfg: ModelConfig, caches: l
             seg_bias = bias[li:li + reps_p * npos].reshape(reps_p, npos, -1)
         li += reps * npos
 
-        def body(carry, inp, pattern=pattern):
+        paged = table is not None
+
+        def body(carry, inp, pattern=pattern, seg_cache=seg_cache):
             xc = carry
             lp, cs, b = inp
             new_cs = []
             for pi, kind in enumerate(pattern):
                 bi = None if b is None else b[pi]
-                xc, c2, _ = apply_block_decode(lp[pi], xc, cfg, kind, cs[pi], pos,
+                c = _layer_cache(kind, seg_cache[pi], cs[pi]) if paged \
+                    else cs[pi]
+                xc, c2, _ = apply_block_decode(lp[pi], xc, cfg, kind, c, pos,
                                                bias=bi, table=table,
                                                active=active,
                                                attn_backend=attn_backend)
                 new_cs.append(c2)
             return xc, new_cs
 
-        x, nc = jax.lax.scan(body, x, (seg_params, seg_cache, seg_bias))
+        seg_in = _scan_paged(pattern, seg_cache) if paged else seg_cache
+        x, nc = jax.lax.scan(body, x, (seg_params, seg_in, seg_bias))
+        if paged:
+            nc = _write_paged(pattern, seg_cache, nc, table, pos, active)
         new_caches.append(nc)
     return x, new_caches
 
@@ -451,21 +492,24 @@ def apply_stack_verify(stack_params: list, x: Array, cfg: ModelConfig,
             seg_bias = bias[li:li + reps * npos].reshape(reps, npos, -1)
         li += reps * npos
 
-        def body(carry, inp, pattern=pattern):
+        def body(carry, inp, pattern=pattern, seg_cache=seg_cache):
             xc = carry
             lp, cs, b = inp
             new_cs = []
             for pi, kind in enumerate(pattern):
                 bi = None if b is None else b[pi]
-                xc, c2, _ = apply_block_verify(lp[pi], xc, cfg, kind, cs[pi],
-                                               pos, bias=bi, table=table,
-                                               active=active,
-                                               attn_backend=attn_backend)
+                xc, c2, _ = apply_block_verify(
+                    lp[pi], xc, cfg, kind,
+                    _layer_cache(kind, seg_cache[pi], cs[pi]), pos, bias=bi,
+                    table=table, active=active, attn_backend=attn_backend)
                 new_cs.append(c2)
             return xc, new_cs
 
-        x, nc = jax.lax.scan(body, x, (seg_params, seg_cache, seg_bias))
-        new_caches.append(nc)
+        x, nc = jax.lax.scan(body, x, (seg_params,
+                                       _scan_paged(pattern, seg_cache),
+                                       seg_bias))
+        new_caches.append(_write_paged(pattern, seg_cache, nc, table, pos,
+                                       active))
     return x, new_caches
 
 
@@ -500,17 +544,19 @@ def apply_stack_prefill_chunk(stack_params: list, x: Array, cfg: ModelConfig,
         # outside the scan, write it back after
         seg_in = [jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
                       a, slot, 1, axis=1), cs) if kind not in ("attn", "moe")
-                  else cs for kind, cs in zip(pattern, seg_cache)]
+                  else cs for kind, cs in zip(pattern,
+                                              _scan_paged(pattern, seg_cache))]
 
-        def body(carry, inp, pattern=pattern):
+        def body(carry, inp, pattern=pattern, seg_cache=seg_cache):
             xc = carry
             lp, cs, b = inp
             new_cs = []
             for pi, kind in enumerate(pattern):
                 bi = None if b is None else b[pi]
-                xc, c2, _ = apply_block_prefill_chunk(lp[pi], xc, cfg, kind,
-                                                      cs[pi], table_row, p0,
-                                                      bias=bi)
+                xc, c2, _ = apply_block_prefill_chunk(
+                    lp[pi], xc, cfg, kind,
+                    _layer_cache(kind, seg_cache[pi], cs[pi]), table_row, p0,
+                    bias=bi)
                 new_cs.append(c2)
             return xc, new_cs
 
@@ -519,7 +565,8 @@ def apply_stack_prefill_chunk(stack_params: list, x: Array, cfg: ModelConfig,
                   full, row.astype(full.dtype), slot, axis=1), cs, c2)
               if kind not in ("attn", "moe") else c2
               for kind, cs, c2 in zip(pattern, seg_cache, nc)]
-        new_caches.append(nc)
+        new_caches.append(_write_paged(pattern, seg_cache, nc, table_row[None],
+                                       jnp.reshape(p0, (1,))))
     return x, new_caches
 
 
@@ -542,17 +589,21 @@ def apply_stack_prefill_chunk_multi(stack_params: list, x: Array,
             seg_bias = bias[li:li + reps * npos].reshape(reps, npos, -1)
         li += reps * npos
 
-        def body(carry, inp, pattern=pattern):
+        def body(carry, inp, pattern=pattern, seg_cache=seg_cache):
             xc = carry
             lp, cs, b = inp
             new_cs = []
             for pi, kind in enumerate(pattern):
                 bi = None if b is None else b[pi]
                 xc, c2, _ = apply_block_prefill_chunk_multi(
-                    lp[pi], xc, cfg, kind, cs[pi], tables, p0s, bias=bi)
+                    lp[pi], xc, cfg, kind,
+                    _layer_cache(kind, seg_cache[pi], cs[pi]), tables, p0s,
+                    bias=bi)
                 new_cs.append(c2)
             return xc, new_cs
 
-        x, nc = jax.lax.scan(body, x, (seg_params, seg_cache, seg_bias))
-        new_caches.append(nc)
+        x, nc = jax.lax.scan(body, x, (seg_params,
+                                       _scan_paged(pattern, seg_cache),
+                                       seg_bias))
+        new_caches.append(_write_paged(pattern, seg_cache, nc, tables, p0s))
     return x, new_caches

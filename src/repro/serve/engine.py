@@ -12,9 +12,11 @@ occupancy.
 Memory plane — the page-table layout:
 
   * Each full-attention layer's K/V is a physical **page pool**
-    ``(num_pages, page_size, Hkv, D)`` (stacked over depth by the layer scan).
-    Page 0 is the null/trash page: never allocated, absorbs the writes of
-    inactive decode lanes, read only masked.
+    ``(Hkv, num_pages, page_size * D)``, one row per KV head and page
+    (stacked over depth). Each compiled step's layer scan reads the pools
+    and emits its new K/V, written into the pools once after the scan.
+    Page 0 is the null/trash page: never allocated, absorbs rewrites of
+    pages no lane writes, read only masked.
   * A host-side block table (``serve.paging.PageAllocator``) maps each slot's
     logical pages to physical ones; the device sees the dense
     ``(num_slots, max_pages_per_slot)`` int32 table each tick. With
@@ -136,7 +138,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core import immune
-from ..models import model, transformer
+from ..models import layers, model, transformer
 from . import groups
 from . import spec as specdec
 from .api import (RequestOutput, SamplingParams, ServeRequest,  # noqa: F401
@@ -310,9 +312,9 @@ def _decode_tick(params, cfg: ModelConfig, pool, last, active, table,
                  return_topk: int = 0):
     """One token for every slot (occupied or not) — the single compiled decode
     step. Inactive slots advance neither position nor state; their lane
-    computes a garbage token that the host discards (paged K/V writes of
-    inactive lanes are routed to the null page, slot-row caches are frozen),
-    which keeps the step shape independent of occupancy AND keeps garbage
+    computes a garbage token that the host discards (inactive lanes write no
+    page, slot-row caches are frozen), which keeps the step shape
+    independent of occupancy AND keeps garbage
     lanes from dirtying pages a mid-flight chunked prefill already owns.
     ``attn_backend`` selects the paged attention compute (XLA gather vs the
     Pallas block-table kernel). With ``do_sample``, per-slot sampling runs on
@@ -1501,9 +1503,9 @@ class Engine:
                 if not self.active_host[slot]:
                     continue          # preempted by an earlier slot's growth
                 # decode writes at pos (a spec tick at pos..pos+k, clamped to
-                # the slot's logical capacity — writes past it route to the
-                # null page and belong to tokens the budget check never
-                # emits): append pages lazily at the boundary, preempting the
+                # the slot's logical capacity — writes past it are dropped
+                # and belong to tokens the budget check never emits):
+                # append pages lazily at the boundary, preempting the
                 # lowest-priority resident if the pool is dry
                 cover = min(int(self.pos_host[slot]) + 1 + lookahead,
                             self.ecfg.max_cache)
@@ -1832,16 +1834,18 @@ class Engine:
         if not placed or not per:
             return len(placed)
         pages = jnp.asarray([p for _, p in placed])
-        stacks = [jnp.asarray(np.stack([kv[i * per + j] for i, _ in placed],
-                                       axis=1))
+        stacks = [np.stack([kv[i * per + j] for i, _ in placed], axis=1)
                   for j in range(per)]           # (reps, n, page, Hkv, D)
         lane = iter(range(per))
 
         def scatter(kind, leaf):
             if kind in ("attn", "moe"):
-                jk, jv = next(lane), next(lane)
-                return {"k": leaf["k"].at[:, pages].set(stacks[jk]),
-                        "v": leaf["v"].at[:, pages].set(stacks[jv])}
+                # (reps, n, page, Hkv, D) -> (reps, Hkv, n, page*D) rows
+                return {n: leaf[n].at[:, :, pages].set(jnp.asarray(
+                            np.moveaxis(a, 3, 1).reshape(*leaf[n].shape[:2],
+                                                         len(placed), -1)))
+                        for n, a in (("k", stacks[next(lane)]),
+                                     ("v", stacks[next(lane)]))}
             return leaf
 
         self.pool = {"layers": transformer.map_block_caches(
@@ -1850,14 +1854,16 @@ class Engine:
 
     def _gather_page_kv(self, page: int) -> list:
         """Host copies of one physical page's K/V across every paged layer
-        (k then v per layer, segment order) — the snapshot payload for one
-        pinned page."""
+        (k then v per layer, segment order), each (reps, page, Hkv, D) — the
+        snapshot payload for one pinned page."""
         out: list[np.ndarray] = []
 
         def gather(kind, leaf):
             if kind in ("attn", "moe"):
-                out.append(np.asarray(leaf["k"][:, page]))
-                out.append(np.asarray(leaf["v"][:, page]))
+                for n in ("k", "v"):
+                    rows = leaf[n][:, :, page:page + 1]
+                    out.append(np.asarray(layers.page_view(rows,
+                                                           self.cfg))[:, 0])
             return leaf
 
         transformer.map_block_caches(self.cfg, gather, self.pool["layers"])

@@ -47,9 +47,10 @@ reachable chain. Pressure comes from two places: the pin budget itself
 Layout invariants (the hypothesis suite in ``tests/test_paging.py`` churns these):
 
   * page 0 is the **null page**: never allocated, permanently parked. Unmapped
-    block-table entries point at it, and the decode step routes the writes of
-    inactive slots there, so it doubles as the trash page. Reads of it are
-    always masked, so its contents are irrelevant as long as they stay finite.
+    block-table entries point at it, and the row write after a layer scan
+    routes the rewrite of pages no lane writes there, so it doubles as the
+    trash page. Reads of it are always masked, so its contents are
+    irrelevant as long as they stay finite.
   * ``sum(refcounts) == total live block-table entries`` — every owner of a
     page is counted, and nothing else is. Pinned pages have refcount zero and
     appear in no block table.

@@ -445,7 +445,7 @@ class TestCorruptionGuard:
 
         def poison(kind, leaf):
             if kind in ("attn", "moe"):
-                return {"k": leaf["k"].at[:, page].set(jnp.nan),
+                return {"k": leaf["k"].at[:, :, page].set(jnp.nan),
                         "v": leaf["v"]}
             return leaf
 
@@ -482,7 +482,7 @@ class TestCorruptionGuard:
 
                 def poison(kind, leaf):
                     if kind in ("attn", "moe"):
-                        return {"k": leaf["k"].at[:, page].set(jnp.nan),
+                        return {"k": leaf["k"].at[:, :, page].set(jnp.nan),
                                 "v": leaf["v"]}
                     return leaf
 
