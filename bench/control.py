@@ -42,14 +42,14 @@ def main(argv=None) -> int:
     target = int(spec.cell["check"]["served_tokens"])
     for seed in [int(s) for s in args.seeds.split(",")]:
         w, params = harness.served_weights(spec, seed)
-        sv = harness.serve(spec, params, seed, args.seconds, False,
+        sv = harness.serve(spec, w, params, seed, args.seconds, False,
                            time.perf_counter())
         del params
         sample = harness.sample_checked(sv.run, seed, target)
         row = {"seed": seed, "requests": len(sample)}
         if sample:
             t0 = time.perf_counter()
-            g = harness.logit_gaps(w, spec.model, sample)
+            g = harness.logit_gaps(spec, w, sample)
             row["reference_s"] = time.perf_counter() - t0
             row["tokens"] = int(sum(x.size for x in g))
             g = np.concatenate(g)
@@ -57,7 +57,7 @@ def main(argv=None) -> int:
                        program_mismatch=float((g > 0).mean()))
             if seed in ctl:
                 c = np.concatenate(
-                    harness.logit_gaps(w, spec.model, sample, quant="int8"))
+                    harness.logit_gaps(spec, w, sample, quant="int8"))
                 row.update(control_max=float(c.max()),
                            control_mean=float(c.mean()),
                            control_mismatch=float((c > 0).mean()))

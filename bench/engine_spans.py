@@ -11,7 +11,6 @@ reader built on this module then returns nothing.
 """
 from __future__ import annotations
 
-import sys
 from bisect import bisect_left
 from typing import NamedTuple, Optional
 
@@ -28,23 +27,8 @@ class Step(NamedTuple):
 
 def events(run) -> Optional[dict]:
     """The raw events of ``run``'s trace (``tracefile.extract``), or None
-    for an untraced run.
-
-    The harness hands a reader the run with the trace's reduction only, and
-    keeps the raw events on the ``Served`` record that holds the run, in a
-    calling frame: they are taken from there where the run does not carry
-    them itself."""
-    ev = getattr(run, "events", None)
-    if ev is not None or getattr(run, "trace", None) is None:
-        return ev
-    frame = sys._getframe(1)
-    while frame is not None:
-        for v in frame.f_locals.values():
-            if getattr(v, "run", None) is run \
-                    and getattr(v, "trace_events", None) is not None:
-                return v.trace_events
-        frame = frame.f_back
-    return None
+    for an untraced run."""
+    return run.events
 
 
 def steps(run) -> list:

@@ -2,8 +2,11 @@
 
 Everything that belongs to one configuration, traffic mix, cell or metric
 is a file found by name: ``configs/<config>.json``, ``traffic/<mix>.json``,
-``cells/<workload>.json`` and ``metrics/<metric>.py``. This module holds
-the general machinery only.
+``cells/<workload>.json`` and ``metrics/<metric>.py``; the architecture
+module that a configuration names under ``"bench_arch"`` (``archs/*.py``)
+holds its weights, program tree, plain reference and required work. This
+module holds the general machinery only and reaches the model through
+``Spec.arch``.
 
 A run: make the served weights on the device from the seed; build the
 program's engine through the serving CLI's own construction; compile every
@@ -17,7 +20,6 @@ reference.
 """
 from __future__ import annotations
 
-import dataclasses
 import gc
 import importlib.util
 import json
@@ -51,6 +53,7 @@ class Spec:
     workload: str
     chips: int
     model: dict               # configs/<config>.json
+    arch: object              # the module the file names under "bench_arch"
     mix: loadgen.Mix          # traffic/<mix>.json
     cell: dict                # cells/<workload>.json
     end_to_end: list          # metric entries of BENCHMARK.json
@@ -66,6 +69,33 @@ def _applies(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
+def load_arch(m: dict, root: str = ROOT):
+    """The architecture module that configuration ``m`` names under
+    ``"bench_arch"``, by path from ``root``; each file is loaded once."""
+    rel = m.get("bench_arch")
+    if not rel:
+        raise SystemExit(f"bench: configuration {m.get('name')!r} names no "
+                         f"architecture module under 'bench_arch'")
+    path = os.path.abspath(os.path.join(root, rel))
+    if not os.path.isfile(path):
+        raise SystemExit(f"bench: configuration {m.get('name')!r}: its "
+                         f"'bench_arch' {rel!r} is no file")
+    name = "bench_arch_" + "".join(ch if ch.isalnum() else "_"
+                                   for ch in os.path.relpath(path, root))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        # registered before it runs, as an import is: a dataclass looks up
+        # its module while the class is made
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def load_spec(workload: str, root: str = ROOT) -> Spec:
     bench = _load(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -74,9 +104,10 @@ def load_spec(workload: str, root: str = ROOT) -> Spec:
                          f"{sorted(cells)}")
     w = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    model = _load(os.path.join(root, conf["file"]))
     return Spec(
-        workload=workload, chips=w["chips"],
-        model=_load(os.path.join(root, conf["file"])),
+        workload=workload, chips=w["chips"], model=model,
+        arch=load_arch(model, root),
         mix=loadgen.Mix.from_json(
             _load(os.path.join(BENCH, "traffic", f"{w['traffic']}.json"))),
         cell=_load(os.path.join(BENCH, "cells", f"{workload}.json")),
@@ -97,23 +128,6 @@ def reader(name: str):
 # ---------------------------------------------------------------------------
 # the program under test
 # ---------------------------------------------------------------------------
-def program_config(m: dict):
-    """The program's ModelConfig for configuration file ``m``: the
-    program's own entry for ``m["arch"]`` with every size set from the
-    file, so the file is what runs."""
-    from repro import configs
-    if m["hidden_act"] != "silu":
-        raise ValueError(f"unsupported activation {m['hidden_act']!r}")
-    return dataclasses.replace(
-        configs.get_config(m["arch"]), num_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        tie_embeddings=m["tie_word_embeddings"],
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        act="silu", dtype=m["torch_dtype"])
-
-
 def engine_config(m: dict):
     """The serving CLI's ``--stream`` engine at the file's sizes."""
     from repro.launch import serve as serve_cli
@@ -165,9 +179,10 @@ class Run:
     setup_s: float
     requests: list             # every Req, warm-up and drain included
     steps: list
-    model: work.ModelWork
+    model: object              # the architecture module's ``work(m)``
     peak: dict
-    trace: Optional[dict] = None
+    trace: Optional[dict] = None    # ``tracefile.reduce`` of ``events``
+    events: Optional[dict] = None   # the raw trace (``tracefile.extract``)
     traced: Optional[tuple] = None  # (start, end) of the profile, host clock
     pool: dict = field(default_factory=dict)  # KV pool counters
 
@@ -348,13 +363,14 @@ def sample_checked(run: Run, seed: int, target: int) -> list:
     return out
 
 
-def logit_gaps(w: dict, model: dict, sample: list, quant=None) -> list:
+def logit_gaps(spec: Spec, w: dict, sample: list, quant=None) -> list:
     """Per request, how far below the reference's best logit each served
     token lies; with ``quant``, the gap of the token the control puts first
     instead."""
     import jax.numpy as jnp
 
     import reference
+    model, logits = spec.model, spec.arch.logits
     with_ctx = model["engine"]["max_cache"]
     out = []
     for r in sample:
@@ -364,10 +380,10 @@ def logit_gaps(w: dict, model: dict, sample: list, quant=None) -> list:
         padded[:len(seq)] = seq                    # request: causal, so the
         #                                            tail never reaches back
         idx = np.arange(len(r.prompt) - 1, len(seq))
-        ref = reference.logits(w, model, padded)[jnp.asarray(idx)]
+        ref = logits(w, model, padded)[jnp.asarray(idx)]
         chosen = jnp.asarray(served)
         if quant is not None:
-            ctl = reference.logits(w, model, padded, quant=quant)
+            ctl = logits(w, model, padded, quant=quant)
             chosen = jnp.argmax(ctl[jnp.asarray(idx)], axis=-1)
         out.append(np.asarray(reference.gaps(ref, chosen)))
     return out
@@ -383,7 +399,6 @@ class Served:
     memory_peak: int
     late: list
     compiles_in_window: int
-    trace_events: Optional[dict] = None
 
 
 def prepare_device(spec: Spec, require_tpu: bool):
@@ -410,11 +425,10 @@ def served_weights(spec: Spec, seed: int):
     bytes must be the configuration's."""
     import jax
 
-    import weights
-    w = weights.make(spec.model, seed)
-    params = weights.program_params(w)
+    w = spec.arch.make_weights(spec.model, seed)
+    params = spec.arch.program_params(w)
     jax.block_until_ready(params)
-    want = work.ModelWork.from_config(spec.model).param_bytes
+    want = spec.arch.work(spec.model).param_bytes
     got = sum(x.nbytes for x in jax.tree.leaves(params))
     if got != want:
         raise SystemExit(f"bench: parameter bytes {got} differ from the "
@@ -422,11 +436,13 @@ def served_weights(spec: Spec, seed: int):
     return w, params
 
 
-def serve(spec: Spec, params, seed: int, seconds: float, trace: bool,
-          t_start: float, rate: Optional[float] = None) -> Served:
-    """Build the engine over ``params``, warm it, drive the cell's schedule
-    (at ``rate``, default the cell's) and follow the window's requests to
-    their end. The engine is freed before this returns."""
+def serve(spec: Spec, w: dict, params, seed: int, seconds: float,
+          trace: bool, t_start: float,
+          rate: Optional[float] = None) -> Served:
+    """Build the engine over ``params``, the program's tree over the served
+    weights ``w``; warm it, drive the cell's schedule (at ``rate``, default
+    the cell's) and follow the window's requests to their end. The engine
+    is freed before this returns."""
     import jax
     from contextlib import nullcontext
 
@@ -437,7 +453,8 @@ def serve(spec: Spec, params, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, d, **_: compiles.append(1)
         if ev == "/jax/core/compile/backend_compile_duration" else None)
-    eng = Engine(params, program_config(m), engine_config(m))
+    eng = Engine(params, spec.arch.program_config(m), engine_config(m),
+                 **spec.arch.engine_kwargs(w))
     compile_warm(eng, m["vocab_size"], eng.ecfg.page_size)
 
     warm, drain = float(cell["warm_s"]), float(cell["drain_s"])
@@ -490,12 +507,12 @@ def serve(spec: Spec, params, seed: int, seconds: float, trace: bool,
         tdir.cleanup()
     run = Run(spec=spec, seconds=seconds, window=(w0, w1), setup_s=setup_s,
               requests=client.requests, steps=client.steps,
-              model=work.ModelWork.from_config(m), traced=traced, pool=pool,
+              model=spec.arch.work(m), traced=traced, pool=pool,
+              events=events,
               peak=work.peaks(dev.device_kind) if dev.platform == "tpu"
               else {"bf16_flop_s": 1.0, "hbm_byte_s": 1.0})
     return Served(run=run, memory_peak=int(ms.get("peak_bytes_in_use", 0)),
-                  late=client.late, compiles_in_window=in_window,
-                  trace_events=events)
+                  late=client.late, compiles_in_window=in_window)
 
 
 def pool_counters(alloc) -> dict:
@@ -526,7 +543,7 @@ def check(run: Run, w: dict, seed: int) -> tuple:
     the top of the TTFT tail; it is not a wrong answer."""
     c = run.spec.cell["check"]
     sample = sample_checked(run, seed, int(c["served_tokens"]))
-    gaps = np.concatenate(logit_gaps(w, run.spec.model, sample)) \
+    gaps = np.concatenate(logit_gaps(run.spec, w, sample)) \
         if sample else None
     checks = {
         "mean_logit_gap": {"value": None if gaps is None
@@ -553,7 +570,7 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool,
         f"; device {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
         f"compile cache {jax.config.jax_compilation_cache_dir}")
     w, params = served_weights(spec, seed)
-    sv = serve(spec, params, seed, seconds, trace, t_start)
+    sv = serve(spec, w, params, seed, seconds, trace, t_start)
     del params
     run = sv.run
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -561,8 +578,8 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool,
     extra = {}
     if trace:
         import tracefile
-        run.trace = tracefile.reduce(sv.trace_events,
-                                     tracefile.window_of(sv.trace_events))
+        run.trace = tracefile.reduce(run.events,
+                                     tracefile.window_of(run.events))
         device["busy_s"] = run.trace["busy_s"]
         device["window_s"] = run.trace["window_s"]
         extra["breakdown"] = run.trace["breakdown"]

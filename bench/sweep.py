@@ -71,10 +71,10 @@ def main(argv=None) -> int:
     spec = harness.load_spec(args.workload)
     spec.cell = dict(spec.cell, drain_s=args.drain)
     harness.prepare_device(spec, True)
-    _, params = harness.served_weights(spec, args.seed)
+    w, params = harness.served_weights(spec, args.seed)
     limits = None
     for rate in [float(x) for x in args.rates.split(",")]:
-        sv = harness.serve(spec, params, args.seed, args.seconds, False,
+        sv = harness.serve(spec, w, params, args.seed, args.seconds, False,
                            time.perf_counter(), rate=rate)
         if limits is None:
             s0 = summary(sv.run)

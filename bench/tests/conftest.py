@@ -29,10 +29,18 @@ def tiny_spec(model="tiny.json", mix="tiny-agent.json", cell="tiny-cell.json",
         bench = json.load(f)
     c = data(cell)
     c.update(cell_overrides)
-    return harness.Spec(workload="tiny", chips=1, model=data(model),
+    m = data(model)
+    return harness.Spec(workload="tiny", chips=1, model=m,
+                        arch=harness.load_arch(m),
                         mix=loadgen.Mix.from_json(data(mix)), cell=c,
                         end_to_end=bench["end_to_end"],
                         per_layer=bench["per_layer"])
+
+
+def llama():
+    """The llama architecture module, as the harness loads it."""
+    import harness
+    return harness.load_arch({"bench_arch": "bench/archs/llama.py"})
 
 
 def pytest_configure(config):

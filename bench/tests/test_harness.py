@@ -1,7 +1,10 @@
 """A whole run of the harness at test size on the CPU: sound, with the
 timed path broken underneath, and with the int8 control in the program's
-place. The chip check is skipped (``require_tpu=False``); everything after
-it is the run the chip makes."""
+place; for the llama module and for a second architecture, an MoE stack
+whose module lives beside its test configuration. The chip check is
+skipped (``require_tpu=False``); everything after it is the run the chip
+makes."""
+import hashlib
 import os
 import subprocess
 import sys
@@ -12,8 +15,7 @@ import numpy as np
 import pytest
 
 import harness
-import weights
-from conftest import ROOT, data, tiny_spec
+from conftest import ROOT, data, llama, tiny_spec
 
 SEED = 3_000_000_017
 
@@ -75,34 +77,78 @@ def test_token_altered_where_produced_fails(monkeypatch):
     assert c["value"] > c["limit"]
 
 
-def test_int8_control_in_the_programs_place_fails():
+@pytest.mark.parametrize("model, cell", [
+    ("small.json", "small-cell.json"), ("tiny-moe.json", "tiny-moe-cell.json")])
+def test_int8_control_in_the_programs_place_fails(model, cell):
     """The control, the reference at int8 put in the program's place, read
     on a sound run's own sample at the test size where the two separate:
-    the program keeps within the mean-gap limit and the control does not."""
-    spec = tiny_spec(model="small.json", cell="small-cell.json")
+    the program keeps within the mean-gap limit and the control does not.
+    The MoE stack runs through the same harness, with its own module named
+    from its file; its served weights are the program's parameter bytes
+    (``served_weights`` stops the run otherwise)."""
+    spec = tiny_spec(model=model, cell=cell)
+    assert spec.arch.__file__.endswith(spec.model["bench_arch"])
     line, sv, w = run(spec, seconds=10.0)
     assert line["correct"], line["checks"]
     sample = harness.sample_checked(sv.run, SEED,
                                     spec.cell["check"]["served_tokens"])
-    ctl = np.concatenate(harness.logit_gaps(w, spec.model, sample,
-                                            quant="int8"))
+    ctl = np.concatenate(harness.logit_gaps(spec, w, sample, quant="int8"))
     assert ctl.mean() > spec.cell["check"]["mean_logit_gap"]
 
 
-def test_program_tree_is_the_programs_own():
+@pytest.mark.parametrize("name", ["tiny.json", "tiny-moe.json"])
+def test_program_tree_is_the_programs_own(name):
     from repro.models import model
-    m = data("tiny.json")
-    ours = jax.eval_shape(lambda: weights.program_params(weights.make(m, 1)))
+    m = data(name)
+    arch = harness.load_arch(m)
+    ours = jax.eval_shape(lambda: arch.program_params(arch.make_weights(m, 1)))
     theirs = jax.eval_shape(lambda: model.init_params(
-        jax.random.PRNGKey(0), harness.program_config(m)))
+        jax.random.PRNGKey(0), arch.program_config(m)))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
 
 
+@pytest.mark.parametrize("bench_arch", [None, "bench/archs/nothing.py"])
+def test_configuration_without_its_module_stops(tmp_path, bench_arch):
+    """No default and no fallback: a configuration that names no module,
+    or a path that is no file, stops with an error that names it."""
+    import json
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    bench["configs"][0]["file"] = "model.json"
+    m = data("tiny.json")
+    m.pop("bench_arch")
+    if bench_arch:
+        m["bench_arch"] = bench_arch
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (tmp_path / "model.json").write_text(json.dumps(m))
+    with pytest.raises(SystemExit, match=bench_arch or "'bench_arch'"):
+        harness.load_spec(bench["workloads"][0]["name"], root=str(tmp_path))
+
+
+def test_llama_weights_are_unchanged():
+    """The served weights of ``tiny.json`` at one seed, and the program's
+    tree over them, as the benchmark drew them before the architecture
+    modules: a later edit of the draw moves every reading."""
+    w = llama().make_weights(data("tiny.json"), SEED)
+    h = hashlib.sha256()
+    for k in sorted(w):
+        h.update(k.encode())
+        h.update(np.asarray(w[k]).tobytes())
+    assert h.hexdigest() == ("0a6742b560fee405e71a92b42ede2f61"
+                             "5ea3ab30d059d5c3f9b69e0c342abc4b")
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            llama().program_params(w))[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == ("ee5b3fd5769b2198d01f6f31f7e397ea"
+                             "8974c69209484ef43740b62cdb72f40e")
+
+
 def test_weights_follow_the_seed():
     m = data("tiny.json")
-    a, b, c = (weights.make(m, s) for s in (7, 7, 2**31 + 7))
+    a, b, c = (llama().make_weights(m, s) for s in (7, 7, 2**31 + 7))
     assert np.array_equal(a["wq"], b["wq"])
     assert not np.array_equal(a["wq"], c["wq"])
     assert a["wq"].dtype == jax.numpy.bfloat16

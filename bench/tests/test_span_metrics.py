@@ -64,7 +64,7 @@ def test_idle_under_the_immune_update_is_named_by_it():
     ev = synthetic()
     gaps = dict(tracefile.reduce(ev, tracefile.window_of(ev))
                 ["breakdown"]["idle_gaps"])
-    assert gaps["engine.step/engine.immune"] == pytest.approx(0.01)
+    assert gaps["engine.immune"] == pytest.approx(0.01)
 
 
 @pytest.mark.parametrize("name", TRACE_READERS)
@@ -76,19 +76,18 @@ def test_untraced_or_without_engine_spans_reads_nothing(name):
     assert read(SimpleNamespace(events=ev)) is None
 
 
-def _run(window=(0.0, 10.0), requests=(), trace=None):
+def _run(window=(0.0, 10.0), requests=(), trace=None, events=None):
     return harness.Run(spec=None, seconds=window[1] - window[0],
                        window=window, setup_s=0.0, requests=list(requests),
                        steps=[harness.Step(0.0, 11.0, [], [])], model=None,
-                       peak={}, trace=trace)
+                       peak={}, trace=trace, events=events)
 
 
 def test_events_are_found_on_the_record_that_holds_the_run():
-    """The harness keeps the raw events beside the run it hands readers."""
-    run = _run(trace={"busy_s": 0.05, "window_s": 0.1})
-    sv = harness.Served(run=run, memory_peak=0, late=[],
-                        compiles_in_window=0, trace_events=synthetic())
-    assert engine_spans.events(run) is sv.trace_events
+    """The run the harness hands readers carries the raw events."""
+    ev = synthetic()
+    run = _run(trace={"busy_s": 0.05, "window_s": 0.1}, events=ev)
+    assert engine_spans.events(run) is ev
     assert harness.reader("syncs_per_step")(run) == pytest.approx(3.0)
     assert engine_spans.events(_run()) is None
 

@@ -39,9 +39,21 @@ def test_busy_idle_and_programs():
     assert ops["jit__prefill_chunk/fusion.3"] == pytest.approx(0.02)
     gaps = r["breakdown"]["idle_gaps"]
     assert gaps == [("bench.window", pytest.approx(0.03)),
-                    ("bench.step/PjitFunction(_prefill_chunk)",
-                     pytest.approx(0.02)),
-                    ("bench.window/bench.step", pytest.approx(0.002))]
+                    ("PjitFunction(_prefill_chunk)", pytest.approx(0.02)),
+                    ("bench.step", pytest.approx(0.002))]
+
+
+def test_gap_is_named_by_the_innermost_engine_span():
+    """Inside the engine's spans a gap takes the innermost ``engine.*``
+    name, whatever JAX's own spans lie inside it."""
+    host = [["bench.window", 0, 100], ["bench.step", 0, 90],
+            ["engine.step", 1, 88], ["engine.immune", 10, 30],
+            ["PjitFunction(multiply)", 12, 5], ["ParseArguments", 13, 2],
+            ["engine.sync", 50, 20], ["np.asarray", 55, 10]]
+    assert tracefile._host_path(host, 14) == "engine.immune"
+    assert tracefile._host_path(host, 60) == "engine.sync"
+    assert tracefile._host_path(host, 95) == "bench.window"
+    assert tracefile._host_path(host, 100) == "host idle"
 
 
 def test_window_clips_events():

@@ -82,14 +82,13 @@ def _module_of(ops, modules):
 
 
 def _host_path(host, t):
-    """Names of the host spans that contain time ``t``, outermost first,
-    as 'outer/inner' (the two innermost)."""
-    inside = sorted((s, -(d), n) for n, s, d in host if s <= t < s + d)
-    names = []
-    for _, _, n in inside:
-        if not names or names[-1] != n:   # a span nested in its namesake
-            names.append(n)
-    return "/".join(names[-2:]) if names else "host idle"
+    """The name of the innermost ``engine.*`` span that contains time
+    ``t``: what the serving engine was doing. Outside the engine's spans,
+    the innermost span of any kind; outside every span, 'host idle'."""
+    inside = [n for _, _, n in sorted((s, -d, n) for n, s, d in host
+                                      if s <= t < s + d)]
+    engine = [n for n in inside if n.startswith("engine.")]
+    return (engine or inside or ["host idle"])[-1]
 
 
 def reduce(events: dict, window: tuple) -> dict:
